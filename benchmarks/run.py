@@ -33,6 +33,7 @@ def main() -> None:
     # declared-environment preset (flag hygiene) before any kernel compiles
     from repro.runtime import platform
     platform.apply_bench_preset()
+    platform.use_compile_cache()
     import importlib
     t_all = time.time()
     failures = []

@@ -84,14 +84,16 @@ class RoutingConfig:
                                               ``device`` (jitted prefix-sum
                                               construction); ``host`` is the
                                               numpy Vose oracle/debug path
-    ``layout_step`` auto | fused | split      SGD edge-step body: ``fused``
-                                              (one-pass gather+grad+scatter
-                                              kernel, in-place y) wherever
-                                              ``ops.fused_step_supported``;
+    ``layout_step`` auto | fused | split      SGD edge-step body: the
+                                              one-pass gather+grad+scatter
+                                              Pallas kernel on TPU, its
+                                              bitwise jnp oracle elsewhere;
+                                              ``fused`` forces the kernel
+                                              (interpret mode off TPU);
                                               ``split`` is the gather/grad/
-                                              scatter debug path (also taken
+                                              scatter path (also taken
                                               automatically for autodiff
-                                              prob_fns / VMEM-oversized y)
+                                              prob_fns and off CPU/TPU)
     ``knn_stage``   auto | ring | forest      stage-1 KNN under
                                               ``distributed=True``: ``ring``
                                               = the sharded distance ring

@@ -39,7 +39,7 @@ from repro.core.neighbor_explore import sharded_explore_round
 from repro.kernels import ops
 from repro.kernels.ref import INVALID_DIST
 from repro.runtime import sharding as sh
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 
 @functools.lru_cache(maxsize=32)

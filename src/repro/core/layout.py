@@ -32,7 +32,7 @@ from repro.core import layout_engine
 from repro.core.layout_engine import sgd_edge_step
 from repro.core.sampler import (EdgeSampler, NodeSampler,
                                 ShardedEdgeSampler, ShardedNodeSampler)
-from repro.runtime.compat import shard_map
+from jax import shard_map
 from repro.runtime.fault_tolerance import (DegradedModeWarning,
                                            DivergenceWarning, InjectedFault,
                                            LayoutDivergedError,
@@ -131,7 +131,7 @@ def _step_kwargs(edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
         edge_sampler=edge_sampler, neg_sampler=neg_sampler,
         n_negatives=cfg.n_negatives, n_nodes=n_nodes, prob_fn=cfg.prob_fn,
         a=cfg.prob_a, gamma=cfg.gamma, clip=cfg.grad_clip, rho0=cfg.rho0,
-        batch=batch, fused_step=bool(getattr(cfg, "fused_step", True)))
+        batch=batch, layout_step=cfg.routing.layout_step)
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +216,7 @@ def make_local_sgd_fns(mesh, cfg, n_nodes: int, *, batch: int):
                 n_negatives=cfg.n_negatives, n_nodes=n_nodes,
                 prob_fn=cfg.prob_fn, a=cfg.prob_a, gamma=cfg.gamma,
                 clip=cfg.grad_clip, rho0=cfg.rho0, batch=batch,
-                fused_step=bool(getattr(cfg, "fused_step", True)))
+                layout_step=cfg.routing.layout_step)
             # Hogwild-sum sync: the round-start state is this body's own
             # input (replicas enter a round identical), so the delta
             # combine costs no extra dispatch or y0 copy.  Skipped
@@ -395,7 +395,12 @@ def run_layout_local_sgd(key, edge_sampler: EdgeSampler,
             f"({len(stragglers)} flagged round(s); see "
             f"LayoutResult.stragglers)", RuntimeWarning, stacklevel=2)
     done = n_rounds - start_round
-    return LayoutResult(y=y_rep[0], steps=done * H,
+    # the replicas agree after the last round; the embedding leaves the
+    # mesh for the default device, where the caller's own arrays live:
+    # the one-device Pallas kernels that read it (transform, serving,
+    # metrics) cannot be partitioned over a mesh
+    y = jax.device_put(y_rep[0], jax.devices()[0])
+    return LayoutResult(y=y, steps=done * H,
                         edge_samples=done * H * batch * n_dev,
                         stragglers=stragglers)
 
@@ -520,7 +525,7 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
                                       jnp.float32)
                 kwargs["rho0"] = cfg.rho0 * rho0_scale  # traced: no recompile
                 t0 = time.time()
-                if first_chunk and kwargs["fused_step"]:
+                if first_chunk and kwargs["layout_step"] != "split":
                     # degraded-mode guard: donation invalidates y at
                     # dispatch, so snapshot once to make the retry safe
                     y_backup = np.asarray(y)
@@ -533,7 +538,7 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
                         warnings.warn(DegradedModeWarning(
                             "layout_step", "fused", "split", e),
                             stacklevel=2)
-                        kwargs["fused_step"] = False
+                        kwargs["layout_step"] = "split"
                         y = layout_engine.layout_chunk(
                             jnp.asarray(y_backup), kr, step_ids, t_fracs,
                             **kwargs)

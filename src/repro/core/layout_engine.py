@@ -49,7 +49,7 @@ STATIC_ARGNAMES = (
     "gamma",
     "clip",
     "batch",
-    "fused_step",
+    "layout_step",
 )
 
 
@@ -85,7 +85,7 @@ def apply_edge_batch(
     a: float = 1.0,
     gamma: float = 7.0,
     clip: float = 5.0,
-    fused_step: bool = True,
+    layout_step: str = "auto",
     n_frozen: int = 0,
 ):
     """Apply one pre-sampled edge batch to the (N, s) embedding.
@@ -99,22 +99,26 @@ def apply_edge_batch(
 
     ``lr`` is a scalar or a (B,) per-edge vector; ``n_frozen`` masks
     updates to rows below that index to -0.0 (a bitwise no-op add) — the
-    frozen-corpus transform mode.  ``fused_step`` routes through the
-    fully-fused edge-step kernel (``kernels/largevis_step.py``); the
-    split gather/grad/scatter path below remains for autodiff
-    ``prob_fn``s, embeddings past the kernel's TPU VMEM bound
-    (``ops.fused_step_supported``), and debugging; both paths apply
-    updates in the same canonical per-edge interleaved order, so their
-    trajectories match bitwise.
+    frozen-corpus transform mode.  ``layout_step`` is
+    ``RoutingConfig.layout_step``: ``"fused"`` runs the fully-fused
+    edge-step kernel (``kernels/largevis_step.py``; interpret mode off
+    TPU), ``"auto"`` the kernel on TPU and its bitwise jnp oracle
+    elsewhere (``ops.largevis_edge_step``), ``"split"`` the
+    gather/grad/scatter path below — also taken for autodiff
+    ``prob_fn``s and backends without the kernel
+    (``ops.fused_step_supported``).  All three apply updates in the same
+    canonical per-edge interleaved order, so their trajectories match
+    bitwise.
     """
     if (
-        fused_step
+        layout_step != "split"
         and prob_fn == "inv_quadratic"
         and ops.fused_step_supported(y.shape[0], y.shape[1])
     ):
         return ops.largevis_edge_step(
             y, i, j, negs, neg_mask, lr, gamma=gamma, a=a, clip=clip,
-            n_frozen=n_frozen
+            n_frozen=n_frozen, impl="fused" if layout_step == "fused"
+            else "auto"
         )
 
     yi, yj, yneg = y[i], y[j], y[negs]
@@ -157,7 +161,7 @@ def sgd_edge_step(
     clip: float = 5.0,
     rho0: float = 1.0,
     batch: int = 4096,
-    fused_step: bool = True,
+    layout_step: str = "auto",
 ):
     """One SGD step over a freshly sampled edge batch.  t_frac = t/T.
 
@@ -171,14 +175,10 @@ def sgd_edge_step(
     dispatch, :func:`scan_layout_steps` scans it, and the shard_map local-SGD
     bodies inline it — one definition, three drivers.
 
-    ``fused_step`` routes the update through the fully-fused edge-step
-    kernel (``kernels/largevis_step.py``: in-kernel gather + grad +
-    scatter-accumulate, y aliased in place, no (B, M, s) intermediates or
-    (B*(2+M), s) concat buffer).  The split gather/grad/scatter path below
-    remains for autodiff ``prob_fn``s, embeddings past the kernel's TPU
-    VMEM bound (``ops.fused_step_supported``), and ``fused_step=False``
-    debugging; both paths apply updates in the same canonical per-edge
-    interleaved order, so their trajectories match bitwise.
+    ``layout_step`` routes the update (see :func:`apply_edge_batch`):
+    the fused edge-step kernel does gather + grad + scatter-accumulate
+    in one pass with no (B, M, s) intermediates or (B*(2+M), s) concat
+    buffer; ``"split"`` is the gather/grad/scatter path.
     """
     ke, kn, _ = jax.random.split(key, 3)
     i, j = edge_sampler.sample(ke, batch)
@@ -189,7 +189,7 @@ def sgd_edge_step(
     del n_nodes  # == y.shape[0] in every driver; apply_edge_batch re-derives
     return apply_edge_batch(
         y, i, j, negs, neg_mask, lr,
-        prob_fn=prob_fn, a=a, gamma=gamma, clip=clip, fused_step=fused_step
+        prob_fn=prob_fn, a=a, gamma=gamma, clip=clip, layout_step=layout_step
     )
 
 
@@ -230,7 +230,7 @@ def layout_chunk(
     clip: float = 5.0,
     rho0: float = 1.0,
     batch: int = 4096,
-    fused_step: bool = True,
+    layout_step: str = "auto",
 ):
     """Jitted dispatch unit: ``len(step_ids)`` scanned steps, donated ``y``.
 
@@ -252,5 +252,5 @@ def layout_chunk(
         clip=clip,
         rho0=rho0,
         batch=batch,
-        fused_step=fused_step,
+        layout_step=layout_step,
     )

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import knn as knn_lib
+from repro.core.flat import ravel_rows, row_major
 
 
 def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
@@ -38,8 +39,8 @@ def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
     merge_candidates' self-suppression).  Slot assignment via sorted
     scatter: edges sorted by destination, rank within segment."""
     N, K = knn_idx.shape
-    dst = knn_idx.reshape(-1)
-    src = jnp.repeat(jnp.arange(N, dtype=jnp.int32), K)
+    dst = ravel_rows(knn_idx)
+    src = row_major(N, K)[0]
     order = jnp.argsort(dst)
     dst_s, src_s = dst[order], src[order]
     seg_start = jnp.searchsorted(dst_s, jnp.arange(N))
@@ -53,12 +54,19 @@ def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
     return jnp.where(out < 0, rows, out)
 
 
+def _neighbors_of(knn_idx, nbrs):
+    """``knn_idx[nbrs].reshape(T, K * K)``, gathered straight into
+    (T, K*K) without the flattening reshape (see ``core.flat``)."""
+    r, c = row_major(nbrs.shape[1], knn_idx.shape[1])
+    return knn_idx[nbrs[:, r], c]
+
+
 def _tile_explore(x, knn_idx, knn_dist, rev, rows, key, sample: int):
     """One tile of nodes; returns merged (idx (T,K), dist (T,K))."""
     T = rows.shape[0]
     K = knn_idx.shape[1]
     nbrs = knn_idx[rows]                                  # (T, K)
-    fwd = knn_idx[nbrs].reshape(T, K * K)                 # neighbors' nbrs
+    fwd = _neighbors_of(knn_idx, nbrs)                    # neighbors' nbrs
     cand = jnp.concatenate([fwd, rev[rows]], axis=1)
     if sample and sample < cand.shape[1]:
         cols = jax.random.randint(key, (T, sample), 0, cand.shape[1])
@@ -107,7 +115,7 @@ def sharded_explore_round(x_loc, ids_loc, knn_idx_loc, knn_dist_loc, *,
     g_idx = jax.lax.all_gather(knn_idx_loc, axis, tiled=True)   # (Np, K)
     rev = reverse_neighbors(g_idx, r_cap)                       # (Np, r_cap)
     rev_loc = jax.lax.dynamic_slice_in_dim(rev, ids_loc[0], n_loc)
-    fwd = g_idx[knn_idx_loc].reshape(n_loc, K * K)
+    fwd = _neighbors_of(g_idx, knn_idx_loc)
     cand = jnp.concatenate([fwd, rev_loc], axis=1)              # (n_loc, C)
     if sample and sample < cand.shape[1]:
         cols = jax.random.randint(key, (n_loc, sample), 0, cand.shape[1])

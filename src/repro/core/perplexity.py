@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.runtime import sharding as sh
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 
 def _calibrate_rows(knn_sqdist: jax.Array, perplexity, iters: int):

@@ -60,7 +60,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime.compat import shard_map
+from jax import shard_map
+
+from repro.core.flat import ravel_rows, row_major
 
 
 def build_alias(probs: np.ndarray):
@@ -188,7 +190,7 @@ def _pairing_scope():
     an outer non-x64 jit trace."""
     if jax.default_backend() == "tpu":
         return contextlib.nullcontext(), jnp.float32
-    return jax.experimental.enable_x64(), jnp.float64
+    return jax.enable_x64(True), jnp.float64
 
 
 def build_alias_device(probs) -> tuple:
@@ -347,9 +349,9 @@ def _resolve_impl(impl: str) -> str:
 def _build_edge_sampler_device(knn_idx, weights, *,
                                hi_dtype=jnp.float32) -> EdgeSampler:
     N, K = knn_idx.shape
-    src = jnp.repeat(jnp.arange(N, dtype=jnp.int32), K)
-    dst = knn_idx.reshape(-1).astype(jnp.int32)
-    thr, alias = _alias_pairing(weights.reshape(-1), hi_dtype=hi_dtype)
+    src = row_major(N, K)[0]
+    dst = ravel_rows(knn_idx).astype(jnp.int32)
+    thr, alias = _alias_pairing(ravel_rows(weights), hi_dtype=hi_dtype)
     return EdgeSampler(src, dst, thr, alias, N * K)
 
 
@@ -359,7 +361,7 @@ def _build_negative_sampler_device(knn_idx, weights, *, power: float,
     N, _ = knn_idx.shape
     w = jnp.maximum(weights.astype(jnp.float32), 0.0)
     deg = jnp.sum(w, axis=1)                              # out-degree
-    deg = deg.at[knn_idx.reshape(-1)].add(w.reshape(-1))  # + in-degree
+    deg = deg.at[ravel_rows(knn_idx)].add(ravel_rows(w))  # + in-degree
     thr, alias = _alias_pairing(jnp.maximum(deg, 1e-12) ** power,
                                 hi_dtype=hi_dtype)
     return NodeSampler(thr, alias, N)
@@ -473,11 +475,11 @@ def _make_sharded_builder_fn(mesh, axis: str, n_real: int, power: float,
     def body(idx_loc, w_loc, ids_loc):
         n_loc, K = idx_loc.shape
         w = jnp.maximum(w_loc.astype(jnp.float32), 0.0)
-        flat_w = w.reshape(-1)
+        flat_w = ravel_rows(w)
 
         # --- edge table over this shard's own edges --------------------
-        src = jnp.repeat(ids_loc.astype(jnp.int32), K)
-        dst = idx_loc.reshape(-1).astype(jnp.int32)
+        src = ids_loc.astype(jnp.int32)[row_major(n_loc, K)[0]]
+        dst = ravel_rows(idx_loc).astype(jnp.int32)
         ethr, eali = _alias_pairing(flat_w, hi_dtype=hi_dtype)
         t_edge = jnp.sum(flat_w.astype(hi_dtype))
 
@@ -486,7 +488,7 @@ def _make_sharded_builder_fn(mesh, axis: str, n_real: int, power: float,
         # shard scatters into an O(N) partial and one psum completes it
         out_deg = jnp.sum(w, axis=1)
         part = jnp.zeros((n_loc * n_shards,), jnp.float32)
-        part = part.at[idx_loc.reshape(-1)].add(flat_w)
+        part = part.at[dst].add(flat_w)
         in_deg = jax.lax.psum(part, axis)
         deg = out_deg + jax.lax.dynamic_slice_in_dim(in_deg, ids_loc[0],
                                                      n_loc)

@@ -91,11 +91,11 @@ def sample_query_edges(key, p_log, nn_idx, neg_sampler, n_negatives: int):
 @functools.partial(jax.jit, donate_argnums=(0,),
                    static_argnames=("n_negatives", "steps", "rho0",
                                     "prob_fn", "a", "gamma", "clip",
-                                    "fused_step"))
+                                    "layout_step"))
 def _project_scan(y_full, base_key, p_log, nn_idx, neg_sampler, *,
                   n_negatives: int, steps: int, rho0: float,
                   prob_fn: str, a: float, gamma: float, clip: float,
-                  fused_step: bool):
+                  layout_step: str):
     """``steps`` frozen-corpus SGD steps over [corpus; queries].
 
     ``y_full`` is donated (one (N+Q, s) buffer for the whole scan); rows
@@ -116,7 +116,7 @@ def _project_scan(y_full, base_key, p_log, nn_idx, neg_sampler, *,
         lr = rho0 * jnp.maximum(1.0 - tf, 1e-4)
         y = apply_edge_batch(
             y, i, j, negs, neg_mask, lr, prob_fn=prob_fn, a=a, gamma=gamma,
-            clip=clip, fused_step=fused_step, n_frozen=n_frozen)
+            clip=clip, layout_step=layout_step, n_frozen=n_frozen)
         return y, None
 
     y_full, _ = jax.lax.scan(one, y_full, (step_ids, t_fracs))
@@ -162,7 +162,8 @@ def project(x_new, *, x, y, key=None, cfg: LargeVisConfig | None = None,
         y_full, key, jnp.log(p), nn_idx, neg_sampler,
         n_negatives=cfg.n_negatives, steps=int(cfg.transform_steps),
         rho0=float(rho0), prob_fn=cfg.prob_fn, a=cfg.prob_a,
-        gamma=cfg.gamma, clip=cfg.grad_clip, fused_step=bool(cfg.fused_step))
+        gamma=cfg.gamma, clip=cfg.grad_clip,
+        layout_step=cfg.routing.layout_step)
     return y_full[n:], {"nn_idx": nn_idx, "nn_dist": nn_dist, "p": p}
 
 

@@ -234,9 +234,9 @@ def topk_sqdist(a: jax.Array, b: jax.Array, k: int, *,
     if has_codes:
         T = codes_a.shape[1]
         operands += [pad(codes_a.astype(jnp.int32), bm_, 0),
-                     pad(codes_b.astype(jnp.int32), bn_, 0)]
+                     pad(codes_b.astype(jnp.int32), bn_, 0).T]
         in_specs += [pl.BlockSpec((bm_, T), lambda i, j: (i, 0)),
-                     pl.BlockSpec((bn_, T), lambda i, j: (j, 0))]
+                     pl.BlockSpec((T, bn_), lambda i, j: (0, j))]
     has_init = init_ids is not None
     if has_init:
         operands += [pad(init_ids.astype(jnp.int32), bm_, 0),

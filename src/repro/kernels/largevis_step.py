@@ -5,63 +5,46 @@ The split layout step moves the edge batch through HBM ~5x: an XLA gather
 materializes yi/yj/yneg, the gradient kernel reads and rewrites them, and the
 driver concatenates a (B*(2+M), s) update buffer for a scatter-add back into
 y.  This kernel takes the full embedding plus the pre-sampled edge batch and
-does everything in place:
+does everything in place.
 
-  phase 0 (per edge tile): row-gather yi/yj/yneg out of the resident y,
-      compute the attractive/repulsive forces + per-coordinate clip (the
-      same float ops as ``largevis_grad``/``ref.largevis_grads_ref``), and
-      stage the ``-lr*g`` update rows in a VMEM scratch —
-  phase 1 (per edge tile): sequentially accumulate the staged rows into y.
+Layout.  y enters **planar**: (s, N/128, 128), coordinate c of row r at
+``[c, r // 128, r % 128]``.  A resident slab of R rows costs s*R*4 bytes
+of VMEM, not the R*512 bytes a row-major (R, s) block pads to (s on the
+128-lane axis).  Edge operands are planar too (edges along lanes), and the
+gathered rows and staged updates live in one (K*s, B/128, 128) scratch,
+K = 2+M update slots per edge in the canonical per-edge order
+``[i_e, j_e, negs_e,0..M-1]``.  Row ids are read as scalars from SMEM, one
+(K, tile) block of edges per grid step.
 
-The grid is (2, n_tiles) and TPU grids iterate the minor dimension fastest,
-so *every* gather happens before *any* update — the fused step is exactly
-the split step's batch semantics (gather-all, then scatter-all), and the
-sequential phase-1 loop serializes duplicate-index updates in the canonical
-per-edge order ``[i_e, j_e, negs_e,0..M-1]`` — the same order the split
-path's interleaved scatter-add applies, so fused and split trajectories
-match bitwise (see ``ref.fused_edge_step_ref`` for the order contract).
+Grid (2, n_row_tiles, n_edge_tiles), minor dimension fastest:
 
-In-place: y is aliased input->output via ``input_output_aliases``, so no
-second (N, s) buffer and no materialized (B, M, s) HBM intermediates exist
-outside the kernel.  In the default (untiled) mode y's block spec is the
-full array, i.e. y stays resident in VMEM for the whole call — sized for
-~1M nodes at s=2 (an 8 MiB y budget, half of VMEM).
+  phase 0: for every row tile, copy its (s, R/128, 128) slab of y into
+      VMEM and gather, edge by edge, the rows that tile owns into the
+      scratch (an exact lane pick: a masked max over one 128-lane row).
+  phase 1: at the first step compute the forces for the whole batch
+      (``ref.edge_forces``, the oracle's own function) and stage ``-lr*g``
+      in place of the gathered rows; then for every row tile add, edge by
+      edge in canonical order, the updates that land in its slab, and copy
+      the slab back.
 
-Past that budget, ``y_tile=R`` selects the **embedding-tiled** mode: the
-grid becomes (2, ceil(N/R)) over row tiles of y, and each grid step holds
-only one (R, s) slab in VMEM.  Phase 0 sweeps the row tiles, each tile
-contributing exactly the edge-referenced rows it owns into a persistent
-(B, (2+M)*s) gathered-rows scratch (a masked vectorized gather per tile —
-every referenced row is written by precisely one tile, so the assembled
-rows equal a full gather bitwise).  Phase 1 computes all forces once (at
-row tile 0, from the fully-assembled scratch — elementwise per edge, so
-identical bits to the untiled formulation) and then, per row tile, runs
-the same sequential accumulation loop restricted to updates landing in
-the resident slab.  Each update touches exactly one row and rows never
-interact, so restricting the canonical per-edge stream to one tile's rows
-preserves every row's update order — the tiled result is **bitwise equal**
-to the untiled kernel and to ``ref.fused_edge_step_ref`` for any R.  This
-is what turns ``ops.fused_step_supported`` from a size rejection into a
-tiling decision.
+Every gather precedes every update — the split step's batch semantics —
+and duplicate rows accumulate in the canonical order, the order XLA's
+scatter-add applies ``ref.fused_edge_step_ref``'s interleaved update
+stream in.  Updates are row-local, so restricting the stream to one row
+tile keeps each row's order: any row tiling gives the same bits.
 
-Interpret mode (CPU) is not a debug afterthought here: the kernel body
-lowers to XLA ops, turning phase 1 into a fori-loop of row updates that
-beats XLA's general scatter-add by ~1.5x at N=20k — so ``ops`` routes
-``impl="auto"`` to this kernel on every backend.
+The planar copy of y is aliased input->output and stays in HBM; only one
+slab is ever in VMEM.  The drivers carry y as (N, s), so the wrapper
+pads and transposes y into that copy and back on every call: two
+embedding-sized HBM copies per step around the in-place kernel.  ``y_tile=R`` (rounded up to 1024 rows) picks the slab height;
+``ops.largevis_edge_step`` keeps the whole embedding as one slab up to the
+VMEM budget and tiles past it.
 
-``n_frozen=`` is the partial-update (out-of-sample transform) mode: rows
-below ``n_frozen`` are gathered and contribute forces but are never
-written — their phase-1 update is masked to -0.0, which is a bitwise
-no-op add for every f32 value — so a fitted corpus embedding stays
-BIT-identical while appended query rows optimize against it.  ``lr`` may
-be per-edge (B,) so lockstep serving slots at different schedule
-positions share one dispatch.
-
-``gather=`` picks how phase 0 reads rows: ``"take"`` (default) gathers with
-one vectorized ``jnp.take`` per operand — fast everywhere interpret mode
-runs, and maps to Mosaic's dynamic-gather on current TPU toolchains;
-``"loop"`` row-copies via dynamic slices, the conservative TPU fallback.
-Both are bitwise-identical (tested).
+``n_frozen=`` is the out-of-sample transform mode: rows below ``n_frozen``
+are gathered and contribute forces but are never written, so a fitted
+corpus embedding stays BIT-identical while appended query rows optimize
+against it.  ``lr`` may be per-edge (B,) so lockstep serving slots at
+different schedule positions share one dispatch.
 """
 from __future__ import annotations
 
@@ -72,204 +55,115 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.largevis_grad import _resolve_interpret
+from repro.kernels import ref
+from repro.kernels.largevis_grad import LANES, _resolve_interpret, _round_up
+
+# y rows per (8, 128) f32 tile of one planar coordinate
+ROWS = 8 * LANES
 
 
-def _kernel(y_in, i_ref, j_ref, n_ref, mask_ref, lr_ref, y_ref, u_ref,
-            g_ref=None, *, gamma: float, a: float, clip: float, eps: float,
-            tile: int, m: int, s: int, gather: str, n_frozen: int):
+def _kernel(ids_ref, mask_ref, lr_ref, y_in, y_ref, slab, g_ref, *,
+            gamma: float, a: float, clip: float, eps: float, m: int, s: int,
+            b: int, tile: int, rq: int, n_etiles: int, n_frozen: int):
     del y_in  # aliased with y_ref; all access goes through the output ref
-    p = pl.program_id(0)
-    t = pl.program_id(1)
+    p, t, et = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    k_slots = 2 + m
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    off = t * (rq * LANES)                    # first y row of this slab
 
-    @pl.when(p == 0)
-    def _grad():
-        # ---- gather the edge rows out of the resident embedding --------
-        if gather == "take":
-            y = y_ref[...]
-            iv = i_ref[...].reshape(-1)
-            jv = j_ref[...].reshape(-1)
-            yi = jnp.take(y, iv, axis=0)
-            yj = jnp.take(y, jv, axis=0)
-            yn = jnp.take(y, n_ref[...].reshape(-1),
-                          axis=0).reshape(tile, m, s)
-        else:  # "loop": per-row dynamic slices (conservative TPU path)
-            def gbody(e, _):
-                g_ref[e, 0:s] = y_ref[i_ref[e, 0], :]
-                g_ref[e, s:2 * s] = y_ref[j_ref[e, 0], :]
+    @pl.when(et == 0)
+    def _load():
+        pltpu.sync_copy(y_ref.at[:, pl.ds(t * rq, rq), :], slab)
 
-                def nbody(mm, _):
-                    g_ref[e, pl.ds((2 + mm) * s, s)] = y_ref[n_ref[e, mm], :]
-                    return 0
+    def locate(k, e):
+        """(sublane, lane, in-slab) of the row edge e sends to slot k."""
+        r = ids_ref[k, e] - off
+        inside = (r >= 0) & (r < rq * LANES)
+        r = jnp.clip(r, 0, rq * LANES - 1)
+        return r // LANES, r % LANES, inside
 
-                jax.lax.fori_loop(0, m, nbody, 0)
-                return 0
+    def pick(row, ln):
+        """row[ln] as a (1, 1) value — exact for every f32, -0.0 too."""
+        return jnp.max(jnp.where(lane == ln, row, -jnp.inf), axis=1,
+                       keepdims=True)
 
-            jax.lax.fori_loop(0, tile, gbody, 0)
-            g = g_ref[...]
-            yi = g[:, 0:s]
-            yj = g[:, s:2 * s]
-            yn = g[:, 2 * s:].reshape(tile, m, s)
-
-        # ---- forces + clip: the same float ops as largevis_grads_ref ---
-        mask = mask_ref[...].astype(jnp.float32)
-        gi, gj, gn = _forces(yi, yj, yn, mask, gamma=gamma, a=a, clip=clip,
-                             eps=eps)
-        # stage -lr*g rows, per-edge interleaved: [u_i, u_j, u_n0..u_n{M-1}]
-        # (lr enters as a (tile, 1) per-edge block — the layout drivers
-        # broadcast one scalar, the serving engine carries per-slot
-        # schedule positions; a broadcast scalar multiplies bitwise
-        # identically to the old scalar form)
-        lr = lr_ref[...]                                   # (tile, 1)
-        u = jnp.concatenate([gi[:, None, :], gj[:, None, :], gn], axis=1)
-        u_ref[pl.ds(t * tile, tile), :] = (-lr[:, :, None] * u).reshape(
-            tile, (2 + m) * s)
-
-    @pl.when(p == 1)
-    def _scatter():
-        # sequential accumulate: duplicate indices (within an edge, across
-        # edges, across tiles) serialize in canonical per-edge order.
-        # Rows below n_frozen (the fitted corpus in transform mode) get
-        # their update masked to -0.0 — x + (-0.0) == x bitwise for every
-        # f32 including both signed zeros, so frozen rows never change.
-        neg_zero = jnp.float32(-0.0)
-
-        def _acc(rr, u_row):
-            if n_frozen:
-                u_row = jnp.where(rr >= n_frozen, u_row, neg_zero)
-            y_ref[rr, :] = y_ref[rr, :] + u_row
-
-        def body(e, _):
-            u = u_ref[t * tile + e, :].reshape(2 + m, s)
-            _acc(i_ref[e, 0], u[0])
-            _acc(j_ref[e, 0], u[1])
-
-            def nbody(mm, _):
-                _acc(n_ref[e, mm], u[2 + mm])
-                return 0
-
-            jax.lax.fori_loop(0, m, nbody, 0)
-            return 0
-
-        jax.lax.fori_loop(0, tile, body, 0)
-
-
-def _forces(yi, yj, yn, mask, *, gamma, a, clip, eps):
-    """The gradient math shared by both kernel modes (and bit-for-bit the
-    ops of ``largevis_grad``/``ref.largevis_grads_ref``): rowwise over
-    edges, reductions over s only — so any edge-row partitioning computes
-    identical bits."""
-    dij = yi - yj
-    d2 = jnp.sum(dij * dij, axis=-1, keepdims=True)
-    gpos = (2.0 * a / (1.0 + a * d2)) * dij
-    din = yi[:, None, :] - yn
-    dn2 = jnp.sum(din * din, axis=-1, keepdims=True)
-    gneg_i = -2.0 * gamma * din / ((eps + dn2) * (1.0 + a * dn2))
-    gneg_i = gneg_i * mask[..., None]
-    gi = jnp.clip(gpos + jnp.sum(gneg_i, axis=1), -clip, clip)
-    gj = jnp.clip(-gpos, -clip, clip)
-    gn = jnp.clip(-gneg_i, -clip, clip)
-    return gi, gj, gn
-
-
-def _kernel_tiled(y_in, i_ref, j_ref, n_ref, mask_ref, lr_ref, y_ref,
-                  g_ref, u_ref, *, gamma: float, a: float, clip: float,
-                  eps: float, m: int, s: int, b: int, y_tile: int,
-                  n_frozen: int):
-    """Embedding-tiled fused step: only a (y_tile, s) slab of y per step.
-
-    Grid (2, n_row_tiles), minor dim fastest: phase 0 visits every row
-    tile and assembles the gathered edge rows into the persistent
-    ``g_ref`` scratch (each tile contributes the rows it owns via a
-    masked vectorized gather); phase 1 computes the staged ``-lr*g``
-    update rows once (row tile 0 — the gather is complete by then) and
-    accumulates, per row tile, exactly the updates that land in the
-    resident slab, in the canonical per-edge order.  Updates are
-    row-local, so per-tile restriction preserves each row's accumulation
-    order — bitwise equal to the untiled kernel."""
-    del y_in  # aliased with y_ref; all access goes through the output ref
-    p = pl.program_id(0)
-    t = pl.program_id(1)
-    off = t * y_tile
+    def for_each_edge(body):
+        # edges in groups of 128 (one scratch row per slot and coordinate)
+        def group(g, carry):
+            row = et * (tile // LANES) + g
+            body(g, row)
+            return carry
+        jax.lax.fori_loop(0, tile // LANES, group, 0)
 
     @pl.when(p == 0)
     def _gather():
-        @pl.when(t == 0)
-        def _init():
-            g_ref[...] = jnp.zeros_like(g_ref)
+        def body(g, row):
+            def one(el, acc):
+                acc = list(acc)
+                e = g * LANES + el
+                for k in range(k_slots):
+                    q, ln, inside = locate(k, e)
+                    hit = (lane == el) & inside
+                    for c in range(s):
+                        v = pick(slab[c, pl.ds(q, 1), :], ln)
+                        acc[k * s + c] = jnp.where(hit, v, acc[k * s + c])
+                return tuple(acc)
 
-        y = y_ref[...]                                     # (R, s) slab
-        iv = i_ref[...].reshape(-1)                        # (B,)
-        jv = j_ref[...].reshape(-1)
-        nv = n_ref[...].reshape(-1)                        # (B*m,)
+            acc = jax.lax.fori_loop(0, LANES, one, tuple(
+                g_ref[x, pl.ds(row, 1), :] for x in range(k_slots * s)))
+            for x in range(k_slots * s):
+                g_ref[x, pl.ds(row, 1), :] = acc[x]
 
-        def pull(idx):
-            rel = idx - off
-            ok = (rel >= 0) & (rel < y_tile)
-            vals = jnp.take(y, jnp.clip(rel, 0, y_tile - 1), axis=0)
-            return ok[:, None], vals
+        for_each_edge(body)
 
-        ok_i, vi = pull(iv)
-        ok_j, vj = pull(jv)
-        ok_n, vn = pull(nv)
-        g = g_ref[...]
-        gi = jnp.where(ok_i, vi, g[:, 0:s])
-        gj = jnp.where(ok_j, vj, g[:, s:2 * s])
-        gn = jnp.where(ok_n, vn, g[:, 2 * s:].reshape(b * m, s))
-        g_ref[...] = jnp.concatenate(
-            [gi, gj, gn.reshape(b, m * s)], axis=1)
+    @pl.when((p == 1) & (t == 0) & (et == 0))
+    def _grads():
+        y = [g_ref[x] for x in range(k_slots * s)]
+        gi, gj, gn = ref.edge_forces(
+            y[0:s], y[s:2 * s],
+            [y[(2 + mm) * s:(3 + mm) * s] for mm in range(m)],
+            [mask_ref[mm] for mm in range(m)],
+            gamma=gamma, a=a, clip=clip, eps=eps)
+        lr = lr_ref[...]
+        for x, gx in enumerate(gi + gj + [v for gm in gn for v in gm]):
+            g_ref[x] = -lr * gx
 
     @pl.when(p == 1)
-    def _apply():
-        @pl.when(t == 0)
-        def _grad():
-            g = g_ref[...]
-            yi = g[:, 0:s]
-            yj = g[:, s:2 * s]
-            yn = g[:, 2 * s:].reshape(b, m, s)
-            mask = mask_ref[...].astype(jnp.float32)
-            gi, gj, gn = _forces(yi, yj, yn, mask, gamma=gamma, a=a,
-                                 clip=clip, eps=eps)
-            lr = lr_ref[...]                               # (B, 1)
-            u = jnp.concatenate([gi[:, None, :], gj[:, None, :], gn],
-                                axis=1)
-            u_ref[...] = (-lr[:, :, None] * u).reshape(b, (2 + m) * s)
+    def _scatter():
+        def body(g, row):
+            u = [g_ref[x, pl.ds(row, 1), :] for x in range(k_slots * s)]
 
-        def _acc(rr, u_row):
-            # out-of-slab (and frozen-row) updates degrade to rewriting
-            # the current value — a bitwise no-op, like the untiled
-            # kernel's -0.0 add for frozen rows
-            rel = rr - off
-            ok = (rel >= 0) & (rel < y_tile)
-            if n_frozen:
-                ok = ok & (rr >= n_frozen)
-            safe = jnp.clip(rel, 0, y_tile - 1)
-            cur = y_ref[safe, :]
-            y_ref[safe, :] = jnp.where(ok, cur + u_row, cur)
+            def one(el, carry):
+                e = g * LANES + el
+                real = et * tile + e < b          # padded edges never land
+                for k in range(k_slots):
+                    q, ln, inside = locate(k, e)
+                    ok = inside & real
+                    if n_frozen:
+                        ok = ok & (ids_ref[k, e] >= n_frozen)
+                    hit = (lane == ln) & ok
+                    for c in range(s):
+                        v = pick(u[k * s + c], el)
+                        cur = slab[c, pl.ds(q, 1), :]
+                        slab[c, pl.ds(q, 1), :] = jnp.where(hit, cur + v, cur)
+                return carry
 
-        def body(e, _):
-            u = u_ref[e, :].reshape(2 + m, s)
-            _acc(i_ref[e, 0], u[0])
-            _acc(j_ref[e, 0], u[1])
+            jax.lax.fori_loop(0, LANES, one, 0)
 
-            def nbody(mm, _):
-                _acc(n_ref[e, mm], u[2 + mm])
-                return 0
+        for_each_edge(body)
 
-            jax.lax.fori_loop(0, m, nbody, 0)
-            return 0
-
-        jax.lax.fori_loop(0, b, body, 0)
+        @pl.when(et == n_etiles - 1)
+        def _store():
+            pltpu.sync_copy(slab, y_ref.at[:, pl.ds(t * rq, rq), :])
 
 
 @functools.partial(jax.jit, static_argnames=("gamma", "a", "clip", "eps",
-                                             "tile", "interpret", "gather",
+                                             "tile", "interpret",
                                              "n_frozen", "y_tile"))
 def fused_edge_step(y, i, j, negs, neg_mask, lr, *, gamma: float = 7.0,
                     a: float = 1.0, clip: float = 5.0, eps: float = 0.1,
-                    tile: int = 1024, interpret: bool | None = None,
-                    gather: str = "take", n_frozen: int = 0,
-                    y_tile: int = 0):
+                    tile: int = 2048, interpret: bool | None = None,
+                    n_frozen: int = 0, y_tile: int = 0):
     """One in-place SGD update of ``y`` over a sampled edge batch.
 
     y: (N, s) f32; i/j: (B,) int32 edge endpoints; negs: (B, M) int32
@@ -277,120 +171,56 @@ def fused_edge_step(y, i, j, negs, neg_mask, lr, *, gamma: float = 7.0,
     lr: scalar learning rate, or a (B,) per-edge vector (the serving
     engine's lockstep slots sit at different schedule positions — the
     scalar form is the same computation broadcast).  Returns the updated
-    (N, s) embedding (same buffer — y is donated to the kernel via
-    input_output_aliases).
+    (N, s) embedding; the planar copy of y is donated to the kernel via
+    input_output_aliases.
 
-    ``n_frozen``: rows with index < n_frozen are never written (their
-    phase-1 update is masked to -0.0, a bitwise no-op add) — the
+    ``n_frozen``: rows with index < n_frozen are never written — the
     out-of-sample transform mode: corpus rows frozen, query rows moving.
 
-    Any B: the batch is zero-padded to a tile multiple; padded edges point
-    at row 0 with i == j and masked negatives, so their gradient is exactly
-    zero and the padded updates are no-ops.
-
-    ``y_tile=R`` (with ``0 < R < N``) selects the embedding-tiled mode:
-    per grid step only an (R, s) slab of y is resident — the mode that
-    lifts the full-VMEM-residency size bound (``ops.largevis_edge_step``
-    picks R automatically past the 8 MiB budget).  Bitwise equal to the
-    untiled mode for any R (see module docstring); ``gather``/``tile``
-    are ignored there (the tiled gather is always the vectorized masked
-    form, and edge blocks are whole-batch).
+    Any B: the batch pads to whole edge tiles of ``tile`` edges (a
+    multiple of 128); padded edges gather row 0 and are never applied.
+    ``y_tile=R`` (0 < R < N) holds only an R-row slab of y in VMEM at a
+    time (R rounds up to a multiple of 1024); bitwise equal to the
+    untiled mode for any R (see module docstring).
     """
     interpret = _resolve_interpret(interpret)
-    assert gather in ("take", "loop"), gather
     N, s = y.shape
     B = i.shape[0]
     M = negs.shape[1]
-    if 0 < y_tile < N:
-        return _fused_edge_step_tiled(
-            y, i, j, negs, neg_mask, lr, gamma=gamma, a=a, clip=clip,
-            eps=eps, y_tile=int(y_tile), interpret=interpret,
-            n_frozen=n_frozen)
-    t = min(tile, B)
-    pad = (-B) % t
+    R = _round_up(y_tile if 0 < y_tile < N else N, ROWS)
+    n_rtiles = -(-N // R)
+    n_pad = n_rtiles * R
+    t = min(_round_up(tile, LANES), _round_up(B, LANES))
+    bp = _round_up(B, t)
+    yp = jnp.pad(y.astype(jnp.float32), ((0, n_pad - N), (0, 0)))
+    yp = yp.T.reshape(s, n_pad // LANES, LANES)
+    ids = jnp.concatenate([i[:, None], j[:, None], negs], axis=1)
+    ids = jnp.pad(ids.astype(jnp.int32).T, ((0, 0), (0, bp - B)))
+    mask = jnp.pad(neg_mask.astype(jnp.float32).T, ((0, 0), (0, bp - B)))
     lr = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (B,))
-    if pad:
-        i = jnp.pad(i, (0, pad))
-        j = jnp.pad(j, (0, pad))
-        negs = jnp.pad(negs, ((0, pad), (0, 0)))
-        neg_mask = jnp.pad(neg_mask, ((0, pad), (0, 0)))
-        lr = jnp.pad(lr, (0, pad))
-    Bp = B + pad
-    n_tiles = Bp // t
+    lr = jnp.pad(lr, (0, bp - B))
     kern = functools.partial(_kernel, gamma=gamma, a=a, clip=clip, eps=eps,
-                             tile=t, m=M, s=s, gather=gather,
-                             n_frozen=n_frozen)
-    return pl.pallas_call(
-        kern,
-        grid=(2, n_tiles),
-        in_specs=[
-            pl.BlockSpec((N, s), lambda p, tt: (0, 0)),
-            pl.BlockSpec((t, 1), lambda p, tt: (tt, 0)),
-            pl.BlockSpec((t, 1), lambda p, tt: (tt, 0)),
-            pl.BlockSpec((t, M), lambda p, tt: (tt, 0)),
-            pl.BlockSpec((t, M), lambda p, tt: (tt, 0)),
-            pl.BlockSpec((t, 1), lambda p, tt: (tt, 0)),
-        ],
-        out_specs=pl.BlockSpec((N, s), lambda p, tt: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, s), jnp.float32),
-        scratch_shapes=(
-            # staged -lr*g update rows, written in phase 0, read in phase 1
-            [pltpu.VMEM((Bp, (2 + M) * s), jnp.float32)]
-            # per-tile gathered rows — only the gather="loop" branch reads it
-            + ([pltpu.VMEM((t, (2 + M) * s), jnp.float32)]
-               if gather == "loop" else [])
-        ),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(y.astype(jnp.float32), i.reshape(-1, 1).astype(jnp.int32),
-      j.reshape(-1, 1).astype(jnp.int32), negs.astype(jnp.int32),
-      neg_mask.astype(jnp.float32), lr.reshape(-1, 1))
-
-
-def _fused_edge_step_tiled(y, i, j, negs, neg_mask, lr, *, gamma, a, clip,
-                           eps, y_tile: int, interpret, n_frozen: int):
-    """The embedding-tiled pallas_call (see ``_kernel_tiled``).
-
-    y pads to a row-tile multiple (padded rows are never referenced by
-    any edge, and are sliced off after the call); edge operands enter as
-    whole-batch blocks — their VMEM footprint is O(B*(2+M)*s), never a
-    function of N.  No batch padding: the untiled mode's padded edges
-    only ever add -0.0 to row 0 (a bitwise no-op), so dropping them
-    keeps the two modes bitwise equal.
-    """
-    N, s = y.shape
-    B = i.shape[0]
-    M = negs.shape[1]
-    R = int(min(y_tile, N))
-    n_tiles = -(-N // R)
-    Np = n_tiles * R
-    yp = y.astype(jnp.float32)
-    if Np != N:
-        yp = jnp.pad(yp, ((0, Np - N), (0, 0)))
-    lr = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (B,))
-    kern = functools.partial(_kernel_tiled, gamma=gamma, a=a, clip=clip,
-                             eps=eps, m=M, s=s, b=B, y_tile=R,
-                             n_frozen=n_frozen)
+                             m=M, s=s, b=B, tile=t, rq=R // LANES,
+                             n_etiles=bp // t, n_frozen=n_frozen)
     out = pl.pallas_call(
         kern,
-        grid=(2, n_tiles),
+        grid=(2, n_rtiles, bp // t),
         in_specs=[
-            pl.BlockSpec((R, s), lambda p, t: (t, 0)),
-            pl.BlockSpec((B, 1), lambda p, t: (0, 0)),
-            pl.BlockSpec((B, 1), lambda p, t: (0, 0)),
-            pl.BlockSpec((B, M), lambda p, t: (0, 0)),
-            pl.BlockSpec((B, M), lambda p, t: (0, 0)),
-            pl.BlockSpec((B, 1), lambda p, t: (0, 0)),
+            pl.BlockSpec((2 + M, t), lambda p, r, e: (0, e),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((M, bp // LANES, LANES), lambda p, r, e: (0, 0, 0)),
+            pl.BlockSpec((bp // LANES, LANES), lambda p, r, e: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((R, s), lambda p, t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((Np, s), jnp.float32),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(yp.shape, jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((B, (2 + M) * s), jnp.float32),   # gathered rows
-            pltpu.VMEM((B, (2 + M) * s), jnp.float32),   # staged -lr*g
+            pltpu.VMEM((s, R // LANES, LANES), jnp.float32),   # y slab
+            # gathered rows, then the staged -lr*g updates, per slot/coord
+            pltpu.VMEM(((2 + M) * s, bp // LANES, LANES), jnp.float32),
         ],
-        input_output_aliases={0: 0},
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(yp, i.reshape(-1, 1).astype(jnp.int32),
-      j.reshape(-1, 1).astype(jnp.int32), negs.astype(jnp.int32),
-      neg_mask.astype(jnp.float32), lr.reshape(-1, 1))
-    return out[:N] if Np != N else out
+    )(ids, mask.reshape(M, bp // LANES, LANES),
+      lr.reshape(bp // LANES, LANES), yp)
+    return out.reshape(s, n_pad).T[:N]

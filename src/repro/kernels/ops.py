@@ -9,22 +9,21 @@ kernel tests assert this).
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.knn_topk import pairwise_sqdist as _sqdist_pallas
 from repro.kernels.knn_topk import topk_sqdist as _topk_pallas
-from repro.kernels.largevis_grad import (
-    largevis_grads_chunked as _lvgrad_pallas,
-)
+from repro.kernels.largevis_grad import _round_up
+from repro.kernels.largevis_grad import largevis_grads as _lvgrad_pallas
+from repro.kernels.largevis_step import ROWS
 from repro.kernels.largevis_step import fused_edge_step as _lvstep_pallas
 from repro.runtime import autotune
 
-# VMEM budget for the fused edge-step kernel's resident slab of y.  This
-# is no longer a support bound: past it the kernel switches to the
-# embedding-tiled mode (y_tile row slabs, bitwise-equal — see
-# ``largevis_step``) instead of being rejected.
+# VMEM budget for the fused edge-step kernel's resident slab of y.  The
+# slab is planar, (s, R/128, 128) f32, so it costs s*4 bytes per row with
+# R rounded up to 1024 rows: 8 MiB holds 1,048,576 rows at s=2.  Not a
+# support bound: past it the kernel tiles y into slabs (``y_tile``).
 _FUSED_MAX_Y_BYTES = 8 * 1024 * 1024
 
 
@@ -51,29 +50,29 @@ def _resolve(impl: str) -> str:
 
 
 def fused_step_supported(n_nodes: int, out_dim: int) -> bool:
-    """Whether ``largevis_edge_step`` may route to the fused kernel.
+    """Whether ``largevis_edge_step`` may route to the fused step.
 
-    True for ANY size on CPU and TPU: past the per-call VMEM budget
-    (``_FUSED_MAX_Y_BYTES`` for the resident y slab) the kernel runs in
-    its embedding-tiled mode — per grid step only a (y_tile, s) slab of
-    y is VMEM-resident, bitwise-equal to the untiled kernel — so size is
-    a tiling decision here, not a rejection (``largevis_edge_step`` picks
-    y_tile below).  Any other backend (GPU) gets the split path: there
-    the sequential per-row update loop would serialize B*(2+M) tiny
-    updates per step, far slower than one parallel scatter-add.
+    True for ANY size on CPU and TPU: past the VMEM budget
+    (``_FUSED_MAX_Y_BYTES`` for the resident slab of y) the kernel tiles
+    y into slabs, bitwise-equal to one slab, so size is a tiling decision
+    (``_fused_y_tile``), not a rejection.  Any other backend (GPU) gets
+    the split path: there the kernel's sequential per-row update loop
+    would serialize B*(2+M) tiny updates per step, far slower than one
+    parallel scatter-add.
     """
     del n_nodes, out_dim  # size no longer bounds support — tiling does
     return jax.default_backend() in ("cpu", "tpu")
 
 
 def _fused_y_tile(n_nodes: int, out_dim: int) -> int:
-    """Row-tile for the fused step's embedding-tiled mode (0 = untiled).
+    """Row-tile for the fused step's slabs of y (0 = one slab).
 
-    Untiled while the whole (N, s) f32 embedding fits the VMEM budget;
-    past it, the largest row count whose slab stays inside the budget."""
-    if n_nodes * out_dim * 4 <= _FUSED_MAX_Y_BYTES:
+    One slab while the whole planar embedding fits the VMEM budget —
+    s*4 bytes per row, rows rounded up to 1024; past it, the largest
+    multiple of 1024 rows whose slab stays inside the budget."""
+    if _round_up(n_nodes, ROWS) * out_dim * 4 <= _FUSED_MAX_Y_BYTES:
         return 0
-    return max(8, _FUSED_MAX_Y_BYTES // (4 * out_dim))
+    return max(ROWS, _FUSED_MAX_Y_BYTES // (4 * out_dim) // ROWS * ROWS)
 
 
 def pairwise_sqdist(a, b, *, impl: str = "auto", **kw):
@@ -119,8 +118,8 @@ def topk_sqdist(a, b, k, *, impl: str = "auto", **kw):
 
 def largevis_grads(yi, yj, yneg, neg_mask, *, gamma=7.0, a=1.0, clip=5.0,
                    eps=0.1, impl: str = "auto", **kw):
-    # chunked entry: pads odd (collision-capped) batches to a tile multiple,
-    # so the kernel is usable inside the scanned layout engine
+    # the kernel pads any batch to whole edge blocks, so it is usable
+    # inside the scanned layout engine at collision-capped odd batches
     if _resolve(impl) == "pallas":
         _tuned("largevis_grads",
                dict(b=yi.shape[0], m=yneg.shape[1], s=yi.shape[1]),
@@ -135,45 +134,43 @@ def largevis_grads(yi, yj, yneg, neg_mask, *, gamma=7.0, a=1.0, clip=5.0,
 def largevis_edge_step(y, i, j, negs, neg_mask, lr, *, gamma=7.0, a=1.0,
                        clip=5.0, eps=0.1, impl: str = "auto",
                        n_frozen: int = 0, **kw):
-    """One fused in-place SGD edge-step update of the (N, s) embedding.
+    """One fused SGD edge-step update of the (N, s) embedding.
 
-    ``n_frozen`` freezes rows below that index (their updates are masked
-    to -0.0 — a bitwise no-op add): the out-of-sample transform /
-    serving mode, where the fitted corpus embedding must stay
-    bit-identical while appended query rows move.  ``lr`` may be a
-    scalar or a (B,) per-edge vector (heterogeneous serving slots).
+    ``n_frozen`` freezes rows below that index (they are never written):
+    the out-of-sample transform / serving mode, where the fitted corpus
+    embedding must stay bit-identical while appended query rows move.
+    ``lr`` may be a scalar or a (B,) per-edge vector (heterogeneous
+    serving slots).
 
     impl:
       "fused" | "pallas" — the fully-fused Pallas kernel
         (``largevis_step.fused_edge_step``: in-kernel gather + grad +
-        sequential scatter-accumulate, y aliased in place).
-      "ref"  — the pure-jnp oracle (``ref.fused_edge_step_ref``).
-      "auto" — the kernel on EVERY backend.  Unlike the wrappers above,
-        interpret mode is not the slow path here: the kernel body lowers
-        to XLA ops and its sequential phase-1 update loop beats XLA's
-        general scatter-add (~1.5x at N=20k on CPU), so the kernel is the
-        fastest formulation on CPU as well as TPU.
+        sequential scatter-accumulate on a planar copy of y, aliased in
+        place; the copy in and out costs two embedding-sized copies per
+        call).  Compiled on TPU, interpret mode elsewhere.
+      "ref"  — the pure-jnp oracle (``ref.fused_edge_step_ref``), bitwise
+        equal to the kernel.
+      "auto" — the kernel on TPU, the oracle elsewhere (the interpreter
+        runs the kernel's per-row loops op by op: the slow path on CPU).
 
-    Callers must check :func:`fused_step_supported` first (a backend
-    gate only, now that the embedding-tiled mode lifts the VMEM size
-    bound); ``core.layout_engine.sgd_edge_step`` falls back to the split
+    Callers must check :func:`fused_step_supported` first;
+    ``core.layout_engine.apply_edge_batch`` falls back to the split
     gather/grad/scatter path when it fails, and for autodiff
-    ``prob_fn``s.  Tile parameters (edge ``tile``, ``gather`` mode, and
-    the embedding row tile ``y_tile``) resolve through the autotuner;
-    when neither the caller nor a tuned entry sets ``y_tile``, it is
-    derived from the VMEM budget (0 = untiled while y fits).
+    ``prob_fn``s.  The edge ``tile`` and the row tile ``y_tile`` resolve
+    through the autotuner; when neither the caller nor a tuned entry sets
+    ``y_tile``, it is derived from the VMEM budget (0 = one slab).
     """
-    if impl in ("auto", "fused", "pallas"):
+    if impl in ("fused", "pallas") or (impl == "auto" and _on_tpu()):
         _tuned("largevis_edge_step",
                dict(n=y.shape[0], b=i.shape[0], m=negs.shape[1],
                     s=y.shape[1]),
-               dict(tile=1024, gather="take", y_tile=0), kw)
+               dict(tile=2048, y_tile=0), kw)
         if not kw.get("y_tile"):
             kw["y_tile"] = _fused_y_tile(y.shape[0], y.shape[1])
         return _lvstep_pallas(y, i, j, negs, neg_mask, lr, gamma=gamma,
                               a=a, clip=clip, eps=eps, n_frozen=n_frozen,
-                              **kw)
-    if impl == "ref":
+                              interpret=not _on_tpu(), **kw)
+    if impl in ("ref", "auto"):
         return ref.fused_edge_step_ref(y, i, j, negs, neg_mask, lr,
                                        gamma=gamma, a=a, clip=clip, eps=eps,
                                        n_frozen=n_frozen)

@@ -71,16 +71,23 @@ def _mask_bad(s, a_ids, b_ids):
     return jnp.where(bad, INVALID_SIM, s)
 
 
-def _mask_tile(s, a_ids, b_ids, codes_a, codes_b, state_ids, dedup: bool,
+def _mask_tile(s, a_ids, b_ids, codes_a, codes_bt, state_ids, dedup: bool,
                skip_bad: bool = False):
     """Invalidate padding (b_id < 0), self-edges, bucket mismatches and —
     when ``dedup`` — candidates whose id already sits in the running state.
     Shared by the streaming ref and the Pallas kernel (the ref conds the
-    bad-mask separately and passes ``skip_bad=True``)."""
+    bad-mask separately and passes ``skip_bad=True``).
+
+    ``codes_a`` is (bm, T) and ``codes_bt`` (T, bn), column codes
+    transposed: the bucket match is an OR of T (bm, bn) compares.  A
+    (bm, bn, T) compare would pad T to 128 lanes in VMEM — 113 MB at the
+    ring's (256, 512) tile against a 16 MB limit."""
     if not skip_bad:
         s = _mask_bad(s, a_ids, b_ids)
     if codes_a is not None:
-        match = (codes_a[:, None, :] == codes_b[None, :, :]).any(-1)
+        match = codes_a[:, 0:1] == codes_bt[0:1, :]
+        for t in range(1, codes_a.shape[1]):
+            match = match | (codes_a[:, t:t + 1] == codes_bt[t:t + 1, :])
         s = jnp.where(match, s, INVALID_SIM)
     if dedup:
         dup = (b_ids[None, :, None] == state_ids[:, None, :]).any(-1)
@@ -181,7 +188,8 @@ def topk_sqdist_ref(a: jax.Array, b: jax.Array, k: int, *,
         merge = "concat" if 1 < n_n < 8 else "tile"
     bT = bp.reshape(n_n, bn, -1)
     biT = bip.reshape(n_n, bn)
-    cbT = codes_b.reshape(n_n, bn, -1) if codes_a is not None else None
+    cbT = (jnp.swapaxes(codes_b.reshape(n_n, bn, -1), 1, 2)
+           if codes_a is not None else None)
     # per-column-tile id range, hoisted: the self/padding mask pass is a
     # numerical no-op unless the tile contains a negative id or its id
     # range overlaps the row tile's — cond it away elsewhere (one fewer
@@ -237,6 +245,72 @@ def topk_sqdist_ref(a: jax.Array, b: jax.Array, k: int, *,
 # largevis_grad: fused attractive + repulsive forces (f(x) = 1/(1+a x^2))
 # ---------------------------------------------------------------------------
 
+def _unfused(p):
+    """``p`` (>= +0 or NaN) unchanged, as a value no compiler folds into
+    the add that consumes it.
+
+    A product feeding an add may be contracted into one fused
+    multiply-add (one rounding instead of two) or not, depending on how
+    the backend vectorizes the surrounding loop — XLA's CPU backend does
+    both within one program — so two layouts of the same math would
+    round differently.  The integer identity ``b | (b >> 31)`` (b >= 0
+    for every non-negative float) is opaque to those rewrites."""
+    b = jax.lax.bitcast_convert_type(p, jnp.int32)
+    return jax.lax.bitcast_convert_type(b | (b >> 31), jnp.float32)
+
+
+def edge_forces(yi, yj, yn, mask, *, gamma: float, a: float, clip: float,
+                eps: float):
+    """The Eqn (6) forces of one edge batch, per coordinate.
+
+    ``yi``/``yj`` are lists of s coordinate arrays, ``yn`` is a list of M
+    such lists and ``mask`` a list of M arrays (or None), all of one
+    shape: the edges.  Every op is elementwise over the edges, and the
+    sums over s and over M are explicit left folds, so the ref, the split
+    kernel and the fused kernel — which call this on different edge
+    layouts — compute the same float ops for every edge.  The squares
+    pass :func:`_unfused` before they are summed.  Returns (gi, gj, gn)
+    in the same nesting, clipped per coordinate.  ``a`` must be positive.
+    """
+    s = len(yi)
+
+    def sq_norm(v):
+        acc = _unfused(v[0] * v[0])
+        for c in range(1, s):
+            acc = acc + _unfused(v[c] * v[c])
+        return acc
+
+    def one_plus_a(d2):
+        return 1.0 + (d2 if a == 1.0 else _unfused(a * d2))
+
+    # positive edge: d/dyi [-log f] = 2a(yi-yj) / (1 + a d2)
+    dij = [yi[c] - yj[c] for c in range(s)]
+    den = one_plus_a(sq_norm(dij))
+    gpos = [(2.0 * a / den) * dij[c] for c in range(s)]
+    # negative: d/dyi [-gamma log(1-f)] = -2 gamma (yi-yn) / ((eps+d2)(1+a d2))
+    gneg = []
+    for mm, ynm in enumerate(yn):
+        din = [yi[c] - ynm[c] for c in range(s)]
+        dn2 = sq_norm(din)
+        den = (eps + dn2) * one_plus_a(dn2)
+        g = [-2.0 * gamma * din[c] / den for c in range(s)]
+        if mask is not None:
+            g = [gc * mask[mm] for gc in g]
+        gneg.append(g)
+    gi = []
+    for c in range(s):
+        g = gpos[c]
+        if gneg:
+            nsum = gneg[0][c]
+            for mm in range(1, len(gneg)):
+                nsum = nsum + gneg[mm][c]
+            g = g + nsum
+        gi.append(jnp.clip(g, -clip, clip))
+    gj = [jnp.clip(-g, -clip, clip) for g in gpos]
+    gn = [[jnp.clip(-g, -clip, clip) for g in gm] for gm in gneg]
+    return gi, gj, gn
+
+
 def largevis_grads_ref(yi, yj, yneg, *, gamma: float = 7.0, a: float = 1.0,
                        clip: float = 5.0, eps: float = 0.1,
                        neg_mask=None):
@@ -248,25 +322,18 @@ def largevis_grads_ref(yi, yj, yneg, *, gamma: float = 7.0, a: float = 1.0,
 
     Returns (gi, gj, gneg): ascent directions are NEGATED (gradient of the
     loss to MINIMIZE), per-coordinate clipped to [-clip, clip] like the
-    reference implementation.
+    reference implementation.  The math is :func:`edge_forces`.
     """
     f32 = jnp.float32
     yi, yj, yneg = yi.astype(f32), yj.astype(f32), yneg.astype(f32)
-    # positive edge: d/dyi [-log f] = 2a(yi-yj) / (1 + a d2)
-    dij = yi - yj                                        # (B,s)
-    d2 = jnp.sum(dij * dij, axis=-1, keepdims=True)      # (B,1)
-    gpos = (2.0 * a / (1.0 + a * d2)) * dij
-    # negative: d/dyi [-gamma log(1-f)] = -2 gamma (yi-yn) / ((eps+d2)(1+a d2))
-    din = yi[:, None, :] - yneg                          # (B,M,s)
-    dn2 = jnp.sum(din * din, axis=-1, keepdims=True)     # (B,M,1)
-    gneg_i = -2.0 * gamma * din / ((eps + dn2) * (1.0 + a * dn2))
-    if neg_mask is not None:
-        gneg_i = gneg_i * neg_mask[..., None]
-    c = clip
-    gi = jnp.clip(gpos + jnp.sum(gneg_i, axis=1), -c, c)
-    gj = jnp.clip(-gpos, -c, c)
-    gneg = jnp.clip(-gneg_i, -c, c)
-    return gi, gj, gneg
+    s, M = yi.shape[1], yneg.shape[1]
+    gi, gj, gn = edge_forces(
+        [yi[:, c] for c in range(s)], [yj[:, c] for c in range(s)],
+        [[yneg[:, mm, c] for c in range(s)] for mm in range(M)],
+        None if neg_mask is None else [neg_mask[:, mm] for mm in range(M)],
+        gamma=gamma, a=a, clip=clip, eps=eps)
+    return (jnp.stack(gi, -1), jnp.stack(gj, -1),
+            jnp.stack([jnp.stack(g, -1) for g in gn], 1))
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,19 @@ this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.runtime.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes.
+
+    The sharded stages place their arrays with ``NamedSharding`` and leave
+    placement inside ``jit`` to the compiler; ``jax.make_mesh`` defaults
+    to Explicit axes, under which plain jitted code that indexes a
+    mesh-sharded array (e.g. the alias build on the per-shard totals)
+    fails its sharding check."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

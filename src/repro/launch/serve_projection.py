@@ -82,10 +82,10 @@ def _prefill_block(xq, x, y, *, k: int, perplexity: float, iters: int):
 @functools.partial(jax.jit, donate_argnums=(0,),
                    static_argnames=("n_negatives", "steps", "rho0",
                                     "prob_fn", "a", "gamma", "clip",
-                                    "fused_step"))
+                                    "layout_step"))
 def _lockstep_step(y_full, key, p_log, nn_idx, ages, active, neg_sampler, *,
                    n_negatives: int, steps: int, rho0: float, prob_fn: str,
-                   a: float, gamma: float, clip: float, fused_step: bool):
+                   a: float, gamma: float, clip: float, layout_step: str):
     """One lockstep transform step over all S slots (active or not).
 
     Slot s sits at schedule position ages[s]/steps -> its own lr (the
@@ -104,7 +104,7 @@ def _lockstep_step(y_full, key, p_log, nn_idx, ages, active, neg_sampler, *,
     lr = rho0 * jnp.maximum(1.0 - t_frac, 1e-4)
     y_full = apply_edge_batch(
         y_full, i, j, negs, neg_mask, lr, prob_fn=prob_fn, a=a, gamma=gamma,
-        clip=clip, fused_step=fused_step, n_frozen=n_frozen)
+        clip=clip, layout_step=layout_step, n_frozen=n_frozen)
     return y_full, ages + active.astype(jnp.int32)
 
 
@@ -291,7 +291,8 @@ class ProjectionEngine:
             self.neg_sampler, n_negatives=self.cfg.n_negatives,
             steps=self.steps, rho0=float(rho0), prob_fn=self.cfg.prob_fn,
             a=self.cfg.prob_a, gamma=self.cfg.gamma,
-            clip=self.cfg.grad_clip, fused_step=bool(self.cfg.fused_step))
+            clip=self.cfg.grad_clip,
+            layout_step=self.cfg.routing.layout_step)
         self.step_no += 1
         for s in range(self.slots):
             if self.requests[s] is not None:

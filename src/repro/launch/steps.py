@@ -14,7 +14,7 @@ from repro.configs import input_specs
 from repro.models import make_model, param_specs
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.runtime import sharding as sh
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 
 def _out_tree_shardings(out_specs, mesh, *, global_batch: int):
@@ -175,7 +175,7 @@ def make_step(cfg, mesh, shape_cfg):
 def make_largevis_step_local(mesh, *, n_nodes: int, n_edges: int,
                              batch: int, out_dim: int = 2,
                              n_negatives: int = 5, sync_every: int = 8,
-                             fused_step: bool = True):
+                             layout_step: str = "auto"):
     """§Perf hillclimb 3: per-shard edge sampling + local-SGD sync.
 
     The v1 step shards the edge alias tables over DP and lets every device
@@ -221,7 +221,7 @@ def make_largevis_step_local(mesh, *, n_nodes: int, n_edges: int,
                 y, base_key, step_ids,
                 jnp.broadcast_to(t_frac, (sync_every,)).astype(jnp.float32),
                 edge_sampler=es, neg_sampler=ns, n_negatives=n_negatives,
-                n_nodes=n_nodes, batch=b_loc, fused_step=fused_step)
+                n_nodes=n_nodes, batch=b_loc, layout_step=layout_step)
             # merge replicas: Hogwild-sum of the deltas (one psum per H
             # steps) — every sampled edge's update lands at full lr, as
             # in the paper's async SGD; a mean would under-step the
@@ -249,7 +249,7 @@ def make_largevis_step_local(mesh, *, n_nodes: int, n_edges: int,
 def make_largevis_step_sharded(mesh, *, n_nodes: int, n_edges: int,
                                batch: int, out_dim: int = 2,
                                n_negatives: int = 5, sync_every: int = 8,
-                               fused_step: bool = True):
+                               layout_step: str = "auto"):
     """Local-SGD step over the *per-shard* sampler tables that
     ``sampler.build_samplers_sharded`` emits (PR 6 pipeline form).
 
@@ -307,7 +307,7 @@ def make_largevis_step_sharded(mesh, *, n_nodes: int, n_edges: int,
                 y, base_key, step_ids,
                 jnp.broadcast_to(t_frac, (sync_every,)).astype(jnp.float32),
                 edge_sampler=es, neg_sampler=ns, n_negatives=n_negatives,
-                n_nodes=n_nodes, batch=b_loc, fused_step=fused_step)
+                n_nodes=n_nodes, batch=b_loc, layout_step=layout_step)
             # Hogwild-sum delta merge (see make_largevis_step_local)
             return y0 + jax.lax.psum(y - y0, dp)
 

@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.models.layers import dense_init
 from repro.runtime import sharding as shd
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 
 def init_moe(key, cfg) -> dict:

@@ -38,7 +38,7 @@ the default (stability beats chasing noise).
 Results-preservation contract: every knob the tuner is allowed to touch
 is a pure performance parameter — row/column tiling of row-local
 computations (``topk_sqdist`` bm/bn/merge/lane, ``symmetrize`` tile,
-grad-kernel tile), the fused edge step's edge-tile/gather-mode/y-tile
+grad-kernel tile), the fused edge step's edge-tile/y-tile
 (the canonical per-edge update order is tile-invariant; see
 ``kernels/largevis_step.py``), and scan-dispatch chunking.  Anything
 that would change results (e.g. ``neighbor_explore``'s per-tile key
@@ -217,7 +217,7 @@ def legacy_default(kernel: str, backend: str | None = None) -> dict:
             return dict(bm=256, bn=512, lane=128)        # knn_topk kernel
         return dict(bm=2048, bn=None, lane=1, merge="auto")   # ref oracle
     if kernel == "largevis_edge_step":
-        return dict(tile=1024, gather="take", y_tile=0)
+        return dict(tile=2048, y_tile=0)
     if kernel == "largevis_grads":
         return dict(tile=2048)
     if kernel == "symmetrize":
@@ -350,6 +350,9 @@ def _sweep_window_fold(shape, backend):
 
 
 def _sweep_edge_step(shape, backend):
+    if backend != "tpu":
+        # the CPU production route is the vectorized jnp oracle — no tile
+        return None
     import jax.numpy as jnp
 
     from repro.kernels import ops
@@ -361,16 +364,14 @@ def _sweep_edge_step(shape, backend):
     j = jax.random.randint(keys[2], (bsz,), 0, n, jnp.int32)
     negs = jax.random.randint(keys[3], (bsz, mneg), 0, n, jnp.int32)
     nm = ((negs != i[:, None]) & (negs != j[:, None])).astype(jnp.float32)
-    tiles = [t for t in (256, 512, 1024, 2048, 4096) if t <= bsz] or [bsz]
-    gathers = ("take", "loop") if backend == "tpu" else ("take",)
-    cands = _uniq(dict(tile=t, gather=g) for t in tiles for g in gathers)
+    tiles = [t for t in (512, 1024, 2048, 4096) if t <= bsz] or [bsz]
 
     def make_thunk(cfg):
         def thunk():
             return ops.largevis_edge_step(y, i, j, negs, nm, 0.5, **cfg)
         return thunk
 
-    return cands, make_thunk
+    return [dict(tile=t) for t in tiles], make_thunk
 
 
 def _sweep_grads(shape, backend):

@@ -4,7 +4,7 @@ Benchmark runs (``benchmarks/run.py``) call :func:`apply_bench_preset`
 first so numbers from different boxes are produced under one declared
 environment instead of whatever flags the shell happened to carry.  All
 helpers only take full effect *before* the JAX backend initializes —
-call them at process start (they warn, not fail, when applied late).
+call them at process start.
 
 Unlike the usual one-shot recipes, every ``XLA_FLAGS`` edit here is a
 **merge**: existing flags survive, and a flag already set by the user
@@ -16,6 +16,7 @@ from __future__ import annotations
 import os
 import warnings
 from multiprocessing import cpu_count
+from pathlib import Path
 
 import jax
 
@@ -29,15 +30,6 @@ GPU_XLA_PRESET = {
     "--xla_gpu_enable_latency_hiding_scheduler": "true",
     "--xla_gpu_enable_highest_priority_async_stream": "true",
 }
-
-
-def _backend_initialized() -> bool:
-    # jax.config updates after backend init silently do nothing for
-    # platform selection; detect so callers get a warning instead
-    try:
-        return jax._src.xla_bridge._backends != {}     # noqa: SLF001
-    except Exception:                                  # jax internals moved
-        return False
 
 
 def merge_xla_flags(flags: dict[str, str], *, override: bool = False) -> str:
@@ -67,13 +59,9 @@ def merge_xla_flags(flags: dict[str, str], *, override: bool = False) -> str:
 def set_platform(platform: str = "cpu") -> None:
     """Select the JAX platform ('cpu' | 'gpu' | 'tpu') + its flag preset.
 
-    Only effective before backend initialization (warns otherwise).  On
-    'gpu' the :data:`GPU_XLA_PRESET` flags merge into ``XLA_FLAGS``.
+    Only effective before backend initialization.  On 'gpu' the
+    :data:`GPU_XLA_PRESET` flags merge into ``XLA_FLAGS``.
     """
-    if _backend_initialized():
-        warnings.warn(
-            f"set_platform({platform!r}) after JAX backend init has no "
-            "effect; call it at process start", stacklevel=2)
     jax.config.update("jax_platform_name", platform)
     if platform == "gpu":
         merge_xla_flags(GPU_XLA_PRESET)
@@ -91,12 +79,23 @@ def set_host_device_count(n: int) -> None:
             f"only {total} CPUs available; exposing {total} devices",
             stacklevel=2)
         n = total
-    if _backend_initialized():
-        warnings.warn(
-            "set_host_device_count after JAX backend init has no effect; "
-            "call it at process start", stacklevel=2)
     merge_xla_flags(
         {"--xla_force_host_platform_device_count": str(n)}, override=True)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no other path is set here.  Otherwise the cache goes to ``.jax_cache``
+    at the repository root: a fixed path, because the path is part of
+    the cache key and a directory that moves never hits."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_debug_nan(flag: bool) -> None:

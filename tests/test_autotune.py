@@ -123,7 +123,7 @@ def test_shape_bucketing_pow2():
 
 def test_legacy_default_registry():
     assert autotune.legacy_default("largevis_edge_step") == \
-        dict(tile=1024, gather="take", y_tile=0)
+        dict(tile=2048, y_tile=0)
     assert autotune.legacy_default("topk_sqdist", backend="tpu") == \
         dict(bm=256, bn=512, lane=128)
     with pytest.raises(KeyError):
@@ -255,12 +255,12 @@ def _batch(N, B, M, s=2, seed=0, lo=0):
     return y, i, j, negs, mask
 
 
-@pytest.mark.parametrize("y_tile", [5, 8, 16, 36, 50])
+@pytest.mark.parametrize("y_tile", [1, 1024, 1500, 2048, 4000])
 def test_tiled_matches_untiled_and_ref_bitwise(y_tile):
-    """Odd N=37 against tiles that divide unevenly (padded slab), exceed N
-    (clamped), and everything between — all bitwise equal to the untiled
-    kernel and the compiled oracle."""
-    y, i, j, negs, mask = _batch(37, 29, 4, s=3, seed=1)
+    """Odd N=2500 against tiles (rounded up to 1024 rows) that divide
+    unevenly (padded slab), exceed N (clamped), and everything between —
+    all bitwise equal to the untiled kernel and the compiled oracle."""
+    y, i, j, negs, mask = _batch(2500, 29, 4, s=3, seed=1)
     kw = dict(gamma=GAMMA, a=A, clip=CLIP, interpret=True)
     tiled = fused_edge_step(y, i, j, negs, mask, 0.37, y_tile=y_tile, **kw)
     flat = fused_edge_step(y, i, j, negs, mask, 0.37, **kw)
@@ -269,46 +269,53 @@ def test_tiled_matches_untiled_and_ref_bitwise(y_tile):
     assert np.array_equal(np.asarray(tiled), np.asarray(want))
 
 
-@pytest.mark.parametrize("y_tile", [4, 7, 32])
+@pytest.mark.parametrize("y_tile", [1, 1024, 2048])
 def test_tiled_duplicate_dense_frozen_per_edge_lr(y_tile):
-    """Every row drawn many times per batch (N=6), half the rows frozen,
-    per-edge learning rates: the tiled accumulation order and the frozen
-    -0.0 no-op writes must survive tiling bitwise."""
-    N, B, M, s = 6, 64, 3, 2
-    y, i, j, negs, mask = _batch(N, B, M, s=s, seed=2)
+    """Six rows spread over three 1024-row tiles, each drawn many times
+    per batch, the first three frozen, per-edge learning rates: the tiled
+    accumulation order and the frozen no-op writes must survive tiling
+    bitwise."""
+    N, B, M, s = 2100, 64, 3, 2
+    rows = jnp.asarray([0, 5, 1030, 1100, 2050, 2090], jnp.int32)
+    y, i, j, negs, mask = _batch(6, B, M, s=s, seed=2)
+    i, j, negs = rows[i], rows[j], rows[negs]
+    y = jax.random.normal(jax.random.key(8), (N, s), jnp.float32)
     lr = jax.random.uniform(jax.random.key(9), (B,), jnp.float32, 0.1, 0.9)
-    kw = dict(gamma=GAMMA, a=A, clip=CLIP, n_frozen=3, interpret=True)
+    kw = dict(gamma=GAMMA, a=A, clip=CLIP, n_frozen=1050, interpret=True)
     tiled = fused_edge_step(y, i, j, negs, mask, lr, y_tile=y_tile, **kw)
     flat = fused_edge_step(y, i, j, negs, mask, lr, **kw)
     want = _ref_step(y, i, j, negs, mask, lr, gamma=GAMMA, a=A, clip=CLIP,
-                     n_frozen=3)
+                     n_frozen=1050)
     assert np.array_equal(np.asarray(tiled), np.asarray(flat))
     assert np.array_equal(np.asarray(tiled), np.asarray(want))
-    assert np.array_equal(np.asarray(tiled[:3]), np.asarray(y[:3]))
+    assert np.array_equal(np.asarray(tiled[:1050]), np.asarray(y[:1050]))
+    assert not np.array_equal(np.asarray(tiled[2050]), np.asarray(y[2050]))
 
 
 def test_ops_route_applies_y_tile_bitwise(tuner):
     """A cached y_tile flows through ops.largevis_edge_step and changes
     nothing but the tiling."""
     autotune.set_mode("cache")
-    y, i, j, negs, mask = _batch(123, 40, 5, seed=3)
-    base = ops.largevis_edge_step(y, i, j, negs, mask, 0.5, gamma=GAMMA,
-                                  a=A, clip=CLIP)
+    y, i, j, negs, mask = _batch(3000, 40, 5, seed=3)
+    kw = dict(gamma=GAMMA, a=A, clip=CLIP, impl="fused")
+    base = ops.largevis_edge_step(y, i, j, negs, mask, 0.5, **kw)
     key = autotune.bucket_key("largevis_edge_step",
-                              dict(n=123, b=40, m=5, s=2))
-    autotune._write_entry(BACKEND, key, {"config": dict(y_tile=48)})
+                              dict(n=3000, b=40, m=5, s=2))
+    autotune._write_entry(BACKEND, key, {"config": dict(y_tile=1024)})
     autotune._mem.clear()
     jax.clear_caches()                    # tiles are static jit args
-    tuned = ops.largevis_edge_step(y, i, j, negs, mask, 0.5, gamma=GAMMA,
-                                   a=A, clip=CLIP)
+    assert autotune.get("largevis_edge_step", dict(n=3000, b=40, m=5, s=2),
+                        dict(tile=2048, y_tile=0))["y_tile"] == 1024
+    tuned = ops.largevis_edge_step(y, i, j, negs, mask, 0.5, **kw)
     assert np.array_equal(np.asarray(base), np.asarray(tuned))
 
 
 def test_tiled_hlo_no_second_full_embedding():
-    """Per grid step the tiled lowering holds an (R, s) slab plus the two
-    (B, (2+M)s) scratches — every buffer other than the whole-embedding
-    in/out (and its padded alias) must fit in one slab/scratch."""
-    N, s, B, M, R = 1000, 2, 64, 3, 384        # pads to Np = 1152
+    """The tiled lowering holds one planar (s, R/128, 128) slab of y plus
+    the gathered-rows scratch — every buffer other than the
+    whole-embedding in/out (and its padded planar copy) must fit in one
+    slab/scratch."""
+    N, s, B, M, R = 3000, 2, 64, 3, 1024       # pads to n_pad = 3072
     y, i, j, negs, mask = _batch(N, B, M, s=s, seed=4)
 
     def f(y_, i_, j_, negs_, mask_):
@@ -317,15 +324,19 @@ def test_tiled_hlo_no_second_full_embedding():
 
     txt = jax.jit(f).lower(y, i, j, negs, mask).as_text()
     n_pad = -(-N // R) * R
-    whole = {(N, s), (n_pad, s)}
-    limit = 4 * max(R * s, B * (2 + M) * s)
+    # whole-embedding shapes: y in and out, and the wrapper's copy of y
+    # into the kernel's planar layout and back (padded, transposed, then
+    # reshaped to the aliased (s, n_pad/128, 128) operand) -- the drivers
+    # carry y as (N, s), so these copies are made on every call
+    whole = {(N, s), (n_pad, s), (s, n_pad), (s, n_pad // 128, 128)}
+    limit = 4 * max(R * s, 128 * (2 + M) * s)
     offenders = sorted({
         (nb, dt, shape) for dt, shape, nb in hlo_checks.iter_buffers(txt)
         if shape not in whole and nb > limit}, reverse=True)
     assert not offenders, offenders[:8]
-    # sanity: the slab and the padded alias really are in the lowering
-    assert hlo_checks.has_buffer(txt, (R, s), "f32")
-    assert hlo_checks.has_buffer(txt, (n_pad, s), "f32")
+    # sanity: the slab and the padded planar copy really are in the lowering
+    assert hlo_checks.has_buffer(txt, (s, R // 128, 128), "f32")
+    assert hlo_checks.has_buffer(txt, (s, n_pad // 128, 128), "f32")
 
 
 def test_fused_step_supported_lifts_size_bound():

@@ -60,7 +60,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     """Checkpoint saved unsharded restores onto a different mesh layout."""
     t = _tree()
     ck.save(tmp_path, 1, t)
-    from repro.runtime.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None)),

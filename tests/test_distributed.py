@@ -23,6 +23,7 @@ import dataclasses
 
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import make_model
 from repro.optim.adamw import adamw_init
@@ -36,14 +37,14 @@ opt = adamw_init(params)
 toks = jax.random.randint(key, (8, 64), 0, cfg.vocab_size)
 batch = {"tokens": toks, "labels": toks}
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 shape_cfg = ShapeConfig("t", "train", 64, 8)
 step, _, in_sh, out_sh = make_train_step(cfg, mesh, shape_cfg, microbatches=2)
 with mesh:
     p2, o2, loss_sharded = jax.jit(step, in_shardings=in_sh,
                                    out_shardings=out_sh)(params, opt, batch)
 
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = make_mesh((1, 1), ("data", "model"))
 step1, _, in_sh1, out_sh1 = make_train_step(cfg, mesh1, shape_cfg,
                                             microbatches=2)
 with mesh1:
@@ -72,7 +73,7 @@ lv = LargeVisConfig(n_neighbors=12, n_trees=4, n_explore_iters=2, window=32,
 idx, dist, w, _ = build_graph(x, jax.random.key(2), cfg=lv)
 es = S.build_edge_sampler(idx, w)
 ns = S.build_negative_sampler(idx, w)
-mesh4 = jax.make_mesh((4,), ("data",))
+mesh4 = make_mesh((4,), ("data",))
 res = run_layout_local_sgd(jax.random.key(3), es, ns, x.shape[0], lv, mesh4)
 assert jnp.isfinite(res.y).all()
 acc = knn_classifier_accuracy(res.y, labels, k=5)
@@ -81,7 +82,7 @@ print("LOCAL_SGD_OK", acc)
 
 # ---- 3) sharded LargeVis step executes ------------------------------------
 from repro.launch.steps import make_largevis_step
-mesh22 = jax.make_mesh((2, 2), ("data", "model"))
+mesh22 = make_mesh((2, 2), ("data", "model"))
 n, e = x.shape[0], int(idx.size)
 fn, specs, in_sh, out_sh = make_largevis_step(mesh22, n_nodes=n, n_edges=e,
                                               batch=512)

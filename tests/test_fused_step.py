@@ -2,7 +2,7 @@
 
 Covers: bit-reproducibility against the pure-jnp oracle (including batches
 dense with duplicate i/j/neg indices, and a numpy sequential loop that pins
-the canonical per-edge update order), gather-mode equivalence, tile padding
+the canonical per-edge update order), edge-tile invariance, tile padding
 for odd (collision-capped) batches and multi-tile batches, collision-masked
 negatives leaving their target rows bitwise untouched, trajectory parity
 fused-vs-split through all three drivers (scan engine, per-step loop,
@@ -18,14 +18,17 @@ import pytest
 
 import hlo_checks
 
-from repro.configs.largevis_default import LargeVisConfig
+from repro.configs.largevis_default import LargeVisConfig, RoutingConfig
 from repro.core import layout as layout_lib
 from repro.core import sampler as sampler_lib
 from repro.kernels import ops, ref
 from repro.kernels.largevis_step import fused_edge_step
-from repro.runtime.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(11)
+# the kernel (interpret mode off TPU); "auto" runs its oracle off TPU
+FUSED = RoutingConfig(layout_step="fused")
+SPLIT = RoutingConfig(layout_step="split")
 GAMMA, A, CLIP = 7.0, 1.0, 5.0
 
 # the bitwise contract is against the *compiled* oracle: eager op-by-op
@@ -46,7 +49,7 @@ def _rand_batch(N, B, M, s=2, seed=0):
 
 
 @pytest.mark.parametrize("N,B,tile", [
-    (300, 64, 64),       # exact tile fit
+    (300, 64, 64),       # one edge tile (tiles round up to 128 edges)
     (300, 37, 16),       # odd batch -> padded remainder tile
     (500, 1500, 512),    # multi-tile grid + padding (T=3)
 ])
@@ -91,15 +94,16 @@ def test_duplicate_indices_accumulate_in_canonical_order():
     np.testing.assert_allclose(np.asarray(got), yn, atol=1e-4, rtol=1e-4)
 
 
-def test_gather_modes_bitwise_identical():
-    """gather="take" (vectorized) and gather="loop" (per-row dynamic
-    slices, the conservative TPU path) are the same kernel."""
-    y, i, j, negs, mask = _rand_batch(400, 200, 5, seed=5)
-    a = fused_edge_step(y, i, j, negs, mask, 0.5, gamma=GAMMA, a=A,
-                        clip=CLIP, tile=64, interpret=True, gather="take")
-    b = fused_edge_step(y, i, j, negs, mask, 0.5, gamma=GAMMA, a=A,
-                        clip=CLIP, tile=64, interpret=True, gather="loop")
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+def test_edge_tiles_bitwise_identical():
+    """The edge tile only sets how many row ids sit in SMEM per grid
+    step: every tiling applies the same update stream."""
+    y, i, j, negs, mask = _rand_batch(400, 700, 5, seed=5)
+    outs = [np.asarray(fused_edge_step(y, i, j, negs, mask, 0.5, gamma=GAMMA,
+                                       a=A, clip=CLIP, tile=t,
+                                       interpret=True))
+            for t in (128, 256, 1024)]
+    for o in outs[1:]:
+        assert np.array_equal(outs[0], o)
 
 
 def test_masked_negatives_leave_rows_untouched():
@@ -125,9 +129,9 @@ def test_masked_negatives_leave_rows_untouched():
 
 
 def test_padding_rows_are_noops():
-    """Tile padding points padded edges at row 0 with zero gradients; a
-    batch whose real edges avoid row 0 must leave row 0 bitwise intact."""
-    N, B, M = 64, 13, 5          # 13 pads up to 16 with tile=16
+    """Tile padding points padded edges at row 0 and never applies them;
+    a batch whose real edges avoid row 0 must leave row 0 bitwise intact."""
+    N, B, M = 64, 13, 5          # 13 pads up to one 128-edge tile
     ks = jax.random.split(KEY, 4)
     y = jax.random.normal(ks[0], (N, 2), jnp.float32)
     i = jax.random.randint(ks[1], (B,), 1, N)
@@ -140,9 +144,10 @@ def test_padding_rows_are_noops():
 
 
 def test_ops_impl_routes():
-    """ops.largevis_edge_step: "fused"/"pallas"/"auto" hit the kernel,
-    "ref" hits the oracle, and all agree bitwise (compiled, as the step
-    bodies use them — eager execution skips XLA's multiply-add fusion)."""
+    """ops.largevis_edge_step: "fused"/"pallas" hit the kernel, "ref" and
+    (off TPU) "auto" hit the oracle, and all agree bitwise (compiled, as
+    the step bodies use them — eager execution skips XLA's multiply-add
+    fusion)."""
     y, i, j, negs, mask = _rand_batch(200, 96, 5, seed=7)
     outs = [np.asarray(jax.jit(
         lambda *args: ops.largevis_edge_step(
@@ -183,8 +188,8 @@ def _run(n, es, ns, **over):
 def test_scan_driver_parity_fused_vs_split(odd_graph):
     n, es, ns = odd_graph
     assert layout_lib._collision_capped_batch(4096, n) % 2 == 1
-    r_fused = _run(n, es, ns, fused_step=True)
-    r_split = _run(n, es, ns, fused_step=False)
+    r_fused = _run(n, es, ns, routing=FUSED)
+    r_split = _run(n, es, ns, routing=SPLIT)
     assert r_fused.steps == r_split.steps
     a, b = np.asarray(r_fused.y), np.asarray(r_split.y)
     assert np.array_equal(a, b), float(np.abs(a - b).max())
@@ -192,9 +197,9 @@ def test_scan_driver_parity_fused_vs_split(odd_graph):
 
 def test_loop_driver_parity_fused_vs_split(odd_graph):
     n, es, ns = odd_graph
-    r_fused = _run(n, es, ns, fused_step=True, steps_per_dispatch=1,
+    r_fused = _run(n, es, ns, routing=FUSED, steps_per_dispatch=1,
                    samples_per_node=20)
-    r_split = _run(n, es, ns, fused_step=False, steps_per_dispatch=1,
+    r_split = _run(n, es, ns, routing=SPLIT, steps_per_dispatch=1,
                    samples_per_node=20)
     assert np.array_equal(np.asarray(r_fused.y), np.asarray(r_split.y))
 
@@ -203,8 +208,8 @@ def test_local_sgd_driver_parity_fused_vs_split(odd_graph):
     n, es, ns = odd_graph
     mesh = make_mesh((1,), ("data",))
     cfg_f = LargeVisConfig(sync_every=4, samples_per_node=32, batch_size=256,
-                           fused_step=True)
-    cfg_s = dataclasses.replace(cfg_f, fused_step=False)
+                           routing=FUSED)
+    cfg_s = dataclasses.replace(cfg_f, routing=SPLIT)
     r_f = layout_lib.run_layout_local_sgd(KEY, es, ns, n, cfg_f, mesh)
     r_s = layout_lib.run_layout_local_sgd(KEY, es, ns, n, cfg_s, mesh)
     assert np.array_equal(np.asarray(r_f.y), np.asarray(r_s.y))
@@ -229,7 +234,7 @@ def test_fused_hlo_emits_no_split_buffers():
     y0 = jax.random.normal(KEY, (n, s), jnp.float32)
 
     def lower(fused):
-        kw = dict(kwargs, fused_step=fused)
+        kw = dict(kwargs, layout_step="fused" if fused else "split")
         return layout_lib.layout_step.lower(
             y0, KEY, jnp.float32(0.1), **kw).as_text()
 

@@ -30,7 +30,7 @@ from repro.core import perplexity as perp
 from repro.core import sampler as S
 from repro.core.largevis import build_graph, largevis
 from repro.launch.mesh import make_data_mesh
-from repro.runtime.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KEY = jax.random.key(0)

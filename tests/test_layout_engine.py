@@ -19,7 +19,7 @@ from repro.core import metrics
 from repro.core import sampler as sampler_lib
 from repro.core.largevis import largevis
 from repro.data.synthetic import gaussian_mixture
-from repro.runtime.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(0)
 
@@ -84,17 +84,17 @@ def test_chunk_donates_y_buffer(small_graph):
 
 
 def test_chunked_kernel_pads_odd_batches():
-    """largevis_grads_chunked == strict kernel semantics at B % tile != 0
-    (the collision cap produces arbitrary odd batches inside the scan)."""
+    """The grads kernel pads B % tile != 0 to whole edge blocks (the
+    collision cap produces arbitrary odd batches inside the scan)."""
     from repro.kernels import ref
-    from repro.kernels.largevis_grad import largevis_grads_chunked
+    from repro.kernels.largevis_grad import largevis_grads
     k1, k2, k3 = jax.random.split(KEY, 3)
     b, m, s = 37, 5, 2
     yi = jax.random.normal(k1, (b, s), jnp.float32)
     yj = jax.random.normal(k2, (b, s), jnp.float32)
     yn = jax.random.normal(k3, (b, m, s), jnp.float32)
     mask = (jax.random.uniform(k1, (b, m)) > 0.2).astype(jnp.float32)
-    got = largevis_grads_chunked(yi, yj, yn, mask, tile=16, interpret=True)
+    got = largevis_grads(yi, yj, yn, mask, tile=16, interpret=True)
     want = ref.largevis_grads_ref(yi, yj, yn, neg_mask=mask)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),

@@ -121,7 +121,7 @@ def test_fused_step_demotes_to_split_on_backend_failure(
 
     def flaky_chunk(y, kr, step_ids, t_fracs, **kw):
         calls["n"] += 1
-        if kw.get("fused_step"):
+        if kw.get("layout_step") != "split":
             raise RuntimeError("XLA fused kernel unavailable")
         return real_chunk(y, kr, step_ids, t_fracs, **kw)
 

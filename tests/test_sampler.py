@@ -35,7 +35,7 @@ from repro.core.largevis import build_graph, largevis
 from repro.core.sampler import sample_alias
 from repro.data.synthetic import gaussian_mixture
 from repro.kernels import ops
-from repro.runtime.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(0)
 

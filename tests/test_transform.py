@@ -62,7 +62,8 @@ def test_frozen_rows_bitwise_kernel_vs_ref():
     negs = jax.random.randint(jax.random.fold_in(k, 3), (b, m), 0, n)
     mask = (negs != i[:, None]).astype(jnp.float32)
     for lr in (0.5, jax.random.uniform(jax.random.fold_in(k, 4), (b,))):
-        got = ops.largevis_edge_step(y, i, j, negs, mask, lr, n_frozen=nf)
+        got = ops.largevis_edge_step(y, i, j, negs, mask, lr, n_frozen=nf,
+                                     impl="fused")
         oracle = jax.jit(functools.partial(
             ref.fused_edge_step_ref, n_frozen=nf))(y, i, j, negs, mask, lr)
         assert np.array_equal(
@@ -82,8 +83,9 @@ def test_per_edge_lr_scalar_broadcast_bitwise():
     j = jax.random.randint(jax.random.fold_in(k, 2), (b,), 0, n)
     negs = jax.random.randint(jax.random.fold_in(k, 3), (b, m), 0, n)
     mask = (negs != i[:, None]).astype(jnp.float32)
-    a = ops.largevis_edge_step(y, i, j, negs, mask, 0.7)
-    v = ops.largevis_edge_step(y, i, j, negs, mask, jnp.full((b,), 0.7))
+    a = ops.largevis_edge_step(y, i, j, negs, mask, 0.7, impl="fused")
+    v = ops.largevis_edge_step(y, i, j, negs, mask, jnp.full((b,), 0.7),
+                               impl="fused")
     assert np.array_equal(np.asarray(a).view(np.uint32),
                           np.asarray(v).view(np.uint32))
 
@@ -94,7 +96,7 @@ def test_per_edge_lr_scalar_broadcast_bitwise():
 
 def test_transform_freezes_corpus_bitwise(data, fitted):
     """The projection's concat embedding keeps every corpus row
-    bit-identical (the kernel's -0.0 masking), and the fitted carrier is
+    bit-identical (the kernel's n_frozen masking), and the fitted carrier is
     not mutated."""
     x, _ = data
     r = fitted.result_
@@ -111,7 +113,7 @@ def test_transform_freezes_corpus_bitwise(data, fitted):
         n_negatives=CFG.n_negatives, steps=int(CFG.transform_steps),
         rho0=float(CFG.rho0), prob_fn=CFG.prob_fn, a=CFG.prob_a,
         gamma=CFG.gamma, clip=CFG.grad_clip,
-        fused_step=bool(CFG.fused_step))
+        layout_step="fused")
     assert np.array_equal(
         np.asarray(out[:N_CORPUS]).view(np.uint32),
         y_before.view(np.uint32))
@@ -129,7 +131,7 @@ def test_project_scan_donates_embedding(fitted):
     y_full = jnp.zeros((N_CORPUS + q, 2), jnp.float32)
     kwargs = dict(n_negatives=CFG.n_negatives, steps=4, rho0=1.0,
                   prob_fn="inv_quadratic", a=1.0, gamma=7.0, clip=5.0,
-                  fused_step=True)
+                  layout_step="fused")
     compiled = tr._project_scan.lower(
         y_full, jax.random.key(0), jnp.zeros((q, k)),
         jnp.zeros((q, k), jnp.int32), r.neg_sampler, **kwargs).compile()
