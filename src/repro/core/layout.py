@@ -33,6 +33,7 @@ from repro.core.layout_engine import sgd_edge_step
 from repro.core.sampler import (EdgeSampler, NodeSampler,
                                 ShardedEdgeSampler, ShardedNodeSampler)
 from jax import shard_map
+from repro.runtime import spans
 from repro.runtime.fault_tolerance import (DegradedModeWarning,
                                            DivergenceWarning, InjectedFault,
                                            LayoutDivergedError,
@@ -441,9 +442,11 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
       chunk demotes ``fused -> split`` for the run with one
       ``DegradedModeWarning`` instead of crashing the fit.
     * a :class:`~repro.runtime.fault_tolerance.Watchdog` times every
-      blocked dispatch and surfaces outliers in ``result.stragglers``
-      (chunks are only blocked-on when a hook/health/fault already
-      forces the sync — a checkpoint-only run keeps the async pipeline:
+      blocked dispatch (from the ``lv.layout.dispatch`` span's start to
+      the ``lv.layout.sync`` span's end, on ``perf_counter``; see
+      ``runtime/spans.py``) and surfaces outliers in
+      ``result.stragglers`` (chunks are only blocked-on when a
+      hook/health/fault already forces the sync — a checkpoint-only run keeps the async pipeline:
       saves go through an off-thread
       :class:`~repro.checkpoint.largevis_state.AsyncStageWriter` fed
       on-device ``jnp.copy`` snapshots, and the watchdog times the
@@ -515,42 +518,52 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
                                 extra=preempt_state["extra"])
 
             guard.set_save_fn(_preempt_save)
+        noted = set()       # (chunk length, route) whose signature is noted
+
+        def chunk(y, step_ids, t_fracs):
+            args = (y, kr, step_ids, t_fracs)
+            sig = (step_ids.shape[0], kwargs["layout_step"])
+            if sig not in noted:
+                noted.add(sig)
+                spans.note("layout_chunk", layout_engine.layout_chunk,
+                           *args, **kwargs)
+            return layout_engine.layout_chunk(*args, **kwargs)
+
         try:
             while t < steps:
                 h = min(H, steps - t)
-                step_ids = jnp.arange(t, t + h, dtype=jnp.int32)
-                # host-side t/steps (f64 rounded to f32) — bit-identical to
-                # the Python loop's jnp.float32(t / steps) schedule
-                t_fracs = jnp.asarray(np.arange(t, t + h) / steps,
-                                      jnp.float32)
-                kwargs["rho0"] = cfg.rho0 * rho0_scale  # traced: no recompile
-                t0 = time.time()
-                if first_chunk and kwargs["layout_step"] != "split":
-                    # degraded-mode guard: donation invalidates y at
-                    # dispatch, so snapshot once to make the retry safe
-                    y_backup = np.asarray(y)
-                    try:
-                        y = layout_engine.layout_chunk(y, kr, step_ids,
-                                                       t_fracs, **kwargs)
-                    except InjectedFault:
-                        raise
-                    except Exception as e:      # backend/compile failure
-                        warnings.warn(DegradedModeWarning(
-                            "layout_step", "fused", "split", e),
-                            stacklevel=2)
-                        kwargs["layout_step"] = "split"
-                        y = layout_engine.layout_chunk(
-                            jnp.asarray(y_backup), kr, step_ids, t_fracs,
-                            **kwargs)
-                else:
-                    y = layout_engine.layout_chunk(y, kr, step_ids, t_fracs,
-                                                   **kwargs)
+                with spans.span("layout.dispatch", steps=h) as dispatch:
+                    step_ids = jnp.arange(t, t + h, dtype=jnp.int32)
+                    # host-side t/steps (f64 rounded to f32) — bit-identical
+                    # to the Python loop's jnp.float32(t / steps) schedule
+                    t_fracs = jnp.asarray(np.arange(t, t + h) / steps,
+                                          jnp.float32)
+                    # traced: no recompile
+                    kwargs["rho0"] = cfg.rho0 * rho0_scale
+                    if first_chunk and kwargs["layout_step"] != "split":
+                        # degraded-mode guard: donation invalidates y at
+                        # dispatch, so snapshot once to make the retry safe
+                        y_backup = np.asarray(y)
+                        try:
+                            y = chunk(y, step_ids, t_fracs)
+                        except InjectedFault:
+                            raise
+                        except Exception as e:  # backend/compile failure
+                            warnings.warn(DegradedModeWarning(
+                                "layout_step", "fused", "split", e),
+                                stacklevel=2)
+                            kwargs["layout_step"] = "split"
+                            y = chunk(jnp.asarray(y_backup), step_ids,
+                                      t_fracs)
+                    else:
+                        y = chunk(y, step_ids, t_fracs)
                 first_chunk = False
                 t += h
                 chunk_i += 1
                 if monitored:
-                    jax.block_until_ready(y)
-                    watchdog.observe(t, time.time() - t0)
+                    with spans.span("layout.sync") as sync:
+                        jax.block_until_ready(y)
+                    watchdog.observe(t, (sync.t1 - dispatch.t0) / 1e9)
                 if fault is not None:
                     y = fault.fire("layout_chunk", y)
                 if health is not None and (

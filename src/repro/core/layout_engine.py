@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from repro.core import objective
 from repro.kernels import ops
+from repro.runtime import spans
 
 # static hyper-parameters of the step body (everything that changes the
 # traced program rather than just its inputs)
@@ -180,17 +181,19 @@ def sgd_edge_step(
     in one pass with no (B, M, s) intermediates or (B*(2+M), s) concat
     buffer; ``"split"`` is the gather/grad/scatter path.
     """
-    ke, kn, _ = jax.random.split(key, 3)
-    i, j = edge_sampler.sample(ke, batch)
-    negs = neg_sampler.sample(kn, (batch, n_negatives))
-    # mask collisions: negative == source or target of the positive edge
-    neg_mask = ((negs != i[:, None]) & (negs != j[:, None])).astype(jnp.float32)
-    lr = rho0 * jnp.maximum(1.0 - t_frac, 1e-4)
+    with spans.scope("layout.sample"):
+        ke, kn, _ = jax.random.split(key, 3)
+        i, j = edge_sampler.sample(ke, batch)
+        negs = neg_sampler.sample(kn, (batch, n_negatives))
+        # mask collisions: negative == source or target of the positive edge
+        neg_mask = ((negs != i[:, None])
+                    & (negs != j[:, None])).astype(jnp.float32)
+        lr = rho0 * jnp.maximum(1.0 - t_frac, 1e-4)
     del n_nodes  # == y.shape[0] in every driver; apply_edge_batch re-derives
-    return apply_edge_batch(
-        y, i, j, negs, neg_mask, lr,
-        prob_fn=prob_fn, a=a, gamma=gamma, clip=clip, layout_step=layout_step
-    )
+    with spans.scope("layout.update"):
+        return apply_edge_batch(
+            y, i, j, negs, neg_mask, lr, prob_fn=prob_fn, a=a, gamma=gamma,
+            clip=clip, layout_step=layout_step)
 
 
 def scan_layout_steps(y, base_key, step_ids, t_fracs, **kw):
@@ -203,7 +206,9 @@ def scan_layout_steps(y, base_key, step_ids, t_fracs, **kw):
 
     def one(y, x):
         sid, tf = x
-        return sgd_edge_step(y, jax.random.fold_in(base_key, sid), tf, **kw), None
+        with spans.scope("layout.sample"):
+            key = jax.random.fold_in(base_key, sid)
+        return sgd_edge_step(y, key, tf, **kw), None
 
     y, _ = jax.lax.scan(one, y, (step_ids, t_fracs))
     return y

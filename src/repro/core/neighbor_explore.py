@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import knn as knn_lib
 from repro.core.flat import ravel_rows, row_major
+from repro.runtime import spans
 
 
 def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
@@ -39,19 +40,20 @@ def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
     merge_candidates' self-suppression).  Slot assignment via sorted
     scatter: edges sorted by destination, rank within segment."""
     N, K = knn_idx.shape
-    dst = ravel_rows(knn_idx)
-    src = row_major(N, K)[0]
-    order = jnp.argsort(dst)
-    dst_s, src_s = dst[order], src[order]
-    seg_start = jnp.searchsorted(dst_s, jnp.arange(N))
-    rank = jnp.arange(N * K) - seg_start[dst_s]
-    keep = rank < r_cap
-    out = jnp.full((N, r_cap), -1, jnp.int32)
-    out = out.at[dst_s, jnp.clip(rank, 0, r_cap - 1)].set(
-        jnp.where(keep, src_s, -1))
-    # replace -1 padding with the row's own index (self -> suppressed)
-    rows = jnp.arange(N, dtype=jnp.int32)[:, None]
-    return jnp.where(out < 0, rows, out)
+    with spans.scope("explore.reverse"):
+        dst = ravel_rows(knn_idx)
+        src = row_major(N, K)[0]
+        order = jnp.argsort(dst)
+        dst_s, src_s = dst[order], src[order]
+        seg_start = jnp.searchsorted(dst_s, jnp.arange(N))
+        rank = jnp.arange(N * K) - seg_start[dst_s]
+        keep = rank < r_cap
+        out = jnp.full((N, r_cap), -1, jnp.int32)
+        out = out.at[dst_s, jnp.clip(rank, 0, r_cap - 1)].set(
+            jnp.where(keep, src_s, -1))
+        # replace -1 padding with the row's own index (self -> suppressed)
+        rows = jnp.arange(N, dtype=jnp.int32)[:, None]
+        return jnp.where(out < 0, rows, out)
 
 
 def _neighbors_of(knn_idx, nbrs):
@@ -65,19 +67,21 @@ def _tile_explore(x, knn_idx, knn_dist, rev, rows, key, sample: int):
     """One tile of nodes; returns merged (idx (T,K), dist (T,K))."""
     T = rows.shape[0]
     K = knn_idx.shape[1]
-    nbrs = knn_idx[rows]                                  # (T, K)
-    fwd = _neighbors_of(knn_idx, nbrs)                    # neighbors' nbrs
-    cand = jnp.concatenate([fwd, rev[rows]], axis=1)
-    if sample and sample < cand.shape[1]:
-        cols = jax.random.randint(key, (T, sample), 0, cand.shape[1])
-        cand = jnp.take_along_axis(cand, cols, axis=1)
-    xc = x[cand]                                          # (T, C, d)
-    xa = x[rows][:, None, :]
-    diff = (xc - xa).astype(jnp.float32)
-    cd = jnp.sum(diff * diff, axis=-1)                    # (T, C)
-    ids = jnp.concatenate([nbrs, cand], axis=1)
-    ds = jnp.concatenate([knn_dist[rows], cd], axis=1)
-    return knn_lib.merge_candidates(ids, ds, K, self_idx=rows)
+    with spans.scope("explore.gather"):
+        nbrs = knn_idx[rows]                              # (T, K)
+        fwd = _neighbors_of(knn_idx, nbrs)                # neighbors' nbrs
+        cand = jnp.concatenate([fwd, rev[rows]], axis=1)
+        if sample and sample < cand.shape[1]:
+            cols = jax.random.randint(key, (T, sample), 0, cand.shape[1])
+            cand = jnp.take_along_axis(cand, cols, axis=1)
+        xc = x[cand]                                      # (T, C, d)
+        xa = x[rows][:, None, :]
+        diff = (xc - xa).astype(jnp.float32)
+        cd = jnp.sum(diff * diff, axis=-1)                # (T, C)
+    with spans.scope("explore.merge"):
+        ids = jnp.concatenate([nbrs, cand], axis=1)
+        ds = jnp.concatenate([knn_dist[rows], cd], axis=1)
+        return knn_lib.merge_candidates(ids, ds, K, self_idx=rows)
 
 
 def sharded_explore_round(x_loc, ids_loc, knn_idx_loc, knn_dist_loc, *,
@@ -111,57 +115,61 @@ def sharded_explore_round(x_loc, ids_loc, knn_idx_loc, knn_dist_loc, *,
     r_cap = r_cap or K
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
-    # --- candidate ids from the exchanged graph -------------------------
-    g_idx = jax.lax.all_gather(knn_idx_loc, axis, tiled=True)   # (Np, K)
-    rev = reverse_neighbors(g_idx, r_cap)                       # (Np, r_cap)
-    rev_loc = jax.lax.dynamic_slice_in_dim(rev, ids_loc[0], n_loc)
-    fwd = _neighbors_of(g_idx, knn_idx_loc)
-    cand = jnp.concatenate([fwd, rev_loc], axis=1)              # (n_loc, C)
-    if sample and sample < cand.shape[1]:
-        cols = jax.random.randint(key, (n_loc, sample), 0, cand.shape[1])
-        cand = jnp.take_along_axis(cand, cols, axis=1)
-    cand = jnp.where(cand >= n_real, ids_loc[:, None], cand)    # pad -> self
+    # candidate ids (the reverse adjacency in its own scope, inside) and
+    # the ring pass that fills their distances
+    with spans.scope("explore.gather"):
+        # --- candidate ids from the exchanged graph ---------------------
+        g_idx = jax.lax.all_gather(knn_idx_loc, axis, tiled=True)  # (Np, K)
+        rev = reverse_neighbors(g_idx, r_cap)                  # (Np, r_cap)
+        rev_loc = jax.lax.dynamic_slice_in_dim(rev, ids_loc[0], n_loc)
+        fwd = _neighbors_of(g_idx, knn_idx_loc)
+        cand = jnp.concatenate([fwd, rev_loc], axis=1)         # (n_loc, C)
+        if sample and sample < cand.shape[1]:
+            cols = jax.random.randint(key, (n_loc, sample), 0, cand.shape[1])
+            cand = jnp.take_along_axis(cand, cols, axis=1)
+        cand = jnp.where(cand >= n_real, ids_loc[:, None], cand)  # pad->self
 
-    # --- ring pass: fill candidate distances from streamed slabs --------
-    C = cand.shape[1]
-    d = x_loc.shape[1]
-    budget = 64 * (1 << 20)                  # ~256 MB of f32 per gather
-    T = int(tile) or max(16, min(n_loc, budget // max(1, C * d)))
-    n_tiles = -(-n_loc // T)
-    pad = n_tiles * T - n_loc
-    if pad:
-        cand_p = jnp.concatenate([cand, jnp.zeros((pad, C), cand.dtype)])
-        x_p = jnp.concatenate([x_loc, jnp.zeros((pad, d), x_loc.dtype)])
-    else:
-        cand_p, x_p = cand, x_loc
-    cand_t = cand_p.reshape(n_tiles, T, C)
-    x_t = x_p.reshape(n_tiles, T, d)
+        # --- ring pass: fill candidate distances from streamed slabs ----
+        C = cand.shape[1]
+        d = x_loc.shape[1]
+        budget = 64 * (1 << 20)                  # ~256 MB of f32 per gather
+        T = int(tile) or max(16, min(n_loc, budget // max(1, C * d)))
+        n_tiles = -(-n_loc // T)
+        pad = n_tiles * T - n_loc
+        if pad:
+            cand_p = jnp.concatenate([cand, jnp.zeros((pad, C), cand.dtype)])
+            x_p = jnp.concatenate([x_loc, jnp.zeros((pad, d), x_loc.dtype)])
+        else:
+            cand_p, x_p = cand, x_loc
+        cand_t = cand_p.reshape(n_tiles, T, C)
+        x_t = x_p.reshape(n_tiles, T, d)
 
-    def ring_step(_, carry):
-        cd, rx, roff = carry
+        def ring_step(_, carry):
+            cd, rx, roff = carry
 
-        def one(args):
-            cand_b, cd_b, x_b = args
-            rel = cand_b - roff
-            in_rng = (rel >= 0) & (rel < n_loc)
-            xc = rx[jnp.clip(rel, 0, n_loc - 1)]                # (T, C, d)
-            diff = (xc - x_b[:, None, :]).astype(jnp.float32)
-            dd = jnp.sum(diff * diff, axis=-1)
-            return jnp.where(in_rng, dd, cd_b)
+            def one(args):
+                cand_b, cd_b, x_b = args
+                rel = cand_b - roff
+                in_rng = (rel >= 0) & (rel < n_loc)
+                xc = rx[jnp.clip(rel, 0, n_loc - 1)]            # (T, C, d)
+                diff = (xc - x_b[:, None, :]).astype(jnp.float32)
+                dd = jnp.sum(diff * diff, axis=-1)
+                return jnp.where(in_rng, dd, cd_b)
 
-        cd = jax.lax.map(one, (cand_t, cd, x_t))
-        rx = jax.lax.ppermute(rx, axis, perm)
-        roff = jax.lax.ppermute(roff, axis, perm)
-        return cd, rx, roff
+            cd = jax.lax.map(one, (cand_t, cd, x_t))
+            rx = jax.lax.ppermute(rx, axis, perm)
+            roff = jax.lax.ppermute(roff, axis, perm)
+            return cd, rx, roff
 
-    cd0 = jnp.full((n_tiles, T, C), knn_lib.INF, jnp.float32)
-    cd, _, _ = jax.lax.fori_loop(
-        0, n_shards, ring_step, (cd0, x_loc, ids_loc[0]))
-    cd = cd.reshape(n_tiles * T, C)[:n_loc]
+        cd0 = jnp.full((n_tiles, T, C), knn_lib.INF, jnp.float32)
+        cd, _, _ = jax.lax.fori_loop(
+            0, n_shards, ring_step, (cd0, x_loc, ids_loc[0]))
+        cd = cd.reshape(n_tiles * T, C)[:n_loc]
 
-    ids = jnp.concatenate([knn_idx_loc, cand], axis=1)
-    ds = jnp.concatenate([knn_dist_loc, cd], axis=1)
-    return knn_lib.merge_candidates(ids, ds, K, self_idx=ids_loc)
+    with spans.scope("explore.merge"):
+        ids = jnp.concatenate([knn_idx_loc, cand], axis=1)
+        ds = jnp.concatenate([knn_dist_loc, cd], axis=1)
+        return knn_lib.merge_candidates(ids, ds, K, self_idx=ids_loc)
 
 
 @functools.partial(jax.jit, static_argnames=("sample", "tile", "r_cap"))
@@ -180,18 +188,20 @@ def _explore_round(x, knn_idx, knn_dist, ikey, *, sample: int, tile: int,
     N, K = knn_idx.shape
     n_tiles = -(-N // tile)
     rev = reverse_neighbors(knn_idx, r_cap)
-    rows = jnp.arange(N, dtype=jnp.int32)
-    rows = jnp.concatenate(
-        [rows, jnp.zeros((n_tiles * tile - N,), jnp.int32)])
-    tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
-        jnp.arange(n_tiles))
+    with spans.scope("explore.gather"):
+        rows = jnp.arange(N, dtype=jnp.int32)
+        rows = jnp.concatenate(
+            [rows, jnp.zeros((n_tiles * tile - N,), jnp.int32)])
+        tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
+            jnp.arange(n_tiles))
 
     def one(args):
         r, tk = args
         return _tile_explore(x, knn_idx, knn_dist, rev, r, tk, sample)
 
     ti, td = jax.lax.map(one, (rows.reshape(n_tiles, tile), tkeys))
-    return ti.reshape(-1, K)[:N], td.reshape(-1, K)[:N]
+    with spans.scope("explore.writeback"):
+        return ti.reshape(-1, K)[:N], td.reshape(-1, K)[:N]
 
 
 @functools.partial(jax.jit, static_argnames=("sample", "tile", "r_cap"))
@@ -207,19 +217,21 @@ def _explore_rows_round(x, knn_idx, knn_dist, rows, ikey, *, sample: int,
     R = rows.shape[0]
     n_tiles = -(-R // tile)
     rev = reverse_neighbors(knn_idx, r_cap)
-    rows_p = jnp.concatenate(
-        [rows, jnp.broadcast_to(rows[:1], (n_tiles * tile - R,))])
-    tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
-        jnp.arange(n_tiles))
+    with spans.scope("explore.gather"):
+        rows_p = jnp.concatenate(
+            [rows, jnp.broadcast_to(rows[:1], (n_tiles * tile - R,))])
+        tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
+            jnp.arange(n_tiles))
 
     def one(args):
         r, tk = args
         return _tile_explore(x, knn_idx, knn_dist, rev, r, tk, sample)
 
     ti, td = jax.lax.map(one, (rows_p.reshape(n_tiles, tile), tkeys))
-    ti = ti.reshape(-1, K)[:R]
-    td = td.reshape(-1, K)[:R]
-    return knn_idx.at[rows].set(ti), knn_dist.at[rows].set(td)
+    with spans.scope("explore.writeback"):
+        ti = ti.reshape(-1, K)[:R]
+        td = td.reshape(-1, K)[:R]
+        return knn_idx.at[rows].set(ti), knn_dist.at[rows].set(td)
 
 
 def neighbor_explore(x, knn_idx, knn_dist, *, iters: int = 1,
@@ -241,31 +253,34 @@ def neighbor_explore(x, knn_idx, knn_dist, *, iters: int = 1,
     still reads the FULL graph (forward and reverse), but only the given
     rows are recomputed and written back.
     """
-    if key is None:
-        key = jax.random.key(0)
     N, K = knn_idx.shape
-    r_cap = r_cap or K
     n_rows = N if rows is None else int(rows.shape[0])
     if n_rows == 0:
         return knn_idx, knn_dist
-    if tile is None:
-        tile = 1024
-        if sample == 0:          # tile is results-neutral only un-sampled
-            from repro.runtime import autotune
-            tile = autotune.get(
-                "neighbor_explore", dict(n=n_rows, k=K, d=x.shape[1]),
-                autotune.legacy_default("neighbor_explore"))["tile"]
-    # keep the per-tile gather under ~256 MB f32
-    budget = 64 * (1 << 20)
-    tile = max(16, min(tile, n_rows,
-                       budget // max(1, (K * K + K) * x.shape[1])))
-    for it in range(iters):
-        if rows is None:
-            knn_idx, knn_dist = _explore_round(
-                x, knn_idx, knn_dist, jax.random.fold_in(key, it),
-                sample=sample, tile=tile, r_cap=r_cap)
-        else:
-            knn_idx, knn_dist = _explore_rows_round(
-                x, knn_idx, knn_dist, rows, jax.random.fold_in(key, it),
-                sample=sample, tile=tile, r_cap=r_cap)
+    with spans.span("explore.call", rows=n_rows):
+        if key is None:
+            key = jax.random.key(0)
+        r_cap = r_cap or K
+        if tile is None:
+            tile = 1024
+            if sample == 0:      # tile is results-neutral only un-sampled
+                from repro.runtime import autotune
+                tile = autotune.get(
+                    "neighbor_explore", dict(n=n_rows, k=K, d=x.shape[1]),
+                    autotune.legacy_default("neighbor_explore"))["tile"]
+        # keep the per-tile gather under ~256 MB f32
+        budget = 64 * (1 << 20)
+        tile = max(16, min(tile, n_rows,
+                           budget // max(1, (K * K + K) * x.shape[1])))
+        static = dict(sample=sample, tile=tile, r_cap=r_cap)
+        for it in range(iters):
+            ikey = jax.random.fold_in(key, it)
+            if rows is None:
+                args = (x, knn_idx, knn_dist, ikey)
+                fn, program = _explore_round, "explore_round"
+            else:
+                args = (x, knn_idx, knn_dist, rows, ikey)
+                fn, program = _explore_rows_round, "explore_rows_round"
+            spans.note(program, fn, *args, **static)
+            knn_idx, knn_dist = fn(*args, **static)
     return knn_idx, knn_dist
