@@ -31,29 +31,46 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import knn as knn_lib
-from repro.core.flat import ravel_rows, row_major
+from repro.core.flat import row_major
 from repro.runtime import spans
 
 
-def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
-    """(N, r_cap) reverse adjacency, padded with self-index (made inert by
-    merge_candidates' self-suppression).  Slot assignment via sorted
-    scatter: edges sorted by destination, rank within segment."""
+def reverse_rows(knn_idx: jax.Array, rows: jax.Array,
+                 r_cap: int) -> jax.Array:
+    """(R, r_cap) reverse neighbours of ``rows``: for each, the first
+    ``r_cap`` sources ``i`` whose list holds it, in ascending ``i``, padded
+    with the row's own index (made inert by merge_candidates'
+    self-suppression).  ``rows`` may repeat ids.
+
+    One sort of the graph's edges by (destination, source); then each
+    row's segment is found by binary search and its first ``r_cap``
+    entries read out: O(N*K log) for the sort and O(R * (log(N*K) +
+    r_cap)) after it, whatever the in-degrees.  The edges enter the sort
+    column by column: the transposed graph, its rows padded to whole
+    tiles, flattens with no gather (``core.flat``) and no slow-compiling
+    relayout; the pads name row N, which no row asks for."""
     N, K = knn_idx.shape
+    R = rows.shape[0]
     with spans.scope("explore.reverse"):
-        dst = ravel_rows(knn_idx)
-        src = row_major(N, K)[0]
-        order = jnp.argsort(dst)
-        dst_s, src_s = dst[order], src[order]
-        seg_start = jnp.searchsorted(dst_s, jnp.arange(N))
-        rank = jnp.arange(N * K) - seg_start[dst_s]
-        keep = rank < r_cap
-        out = jnp.full((N, r_cap), -1, jnp.int32)
-        out = out.at[dst_s, jnp.clip(rank, 0, r_cap - 1)].set(
-            jnp.where(keep, src_s, -1))
-        # replace -1 padding with the row's own index (self -> suppressed)
-        rows = jnp.arange(N, dtype=jnp.int32)[:, None]
-        return jnp.where(out < 0, rows, out)
+        n_pad = -(-N // 1024) * 1024
+        dst = jnp.pad(knn_idx.T, ((0, 0), (0, n_pad - N)), constant_values=N)
+        src = jax.lax.broadcasted_iota(jnp.int32, dst.shape, 1)
+        # unstable: entries with equal keys are equal
+        dst_s, src_s = jax.lax.sort((dst.reshape(-1), src.reshape(-1)),
+                                    num_keys=2, is_stable=False)
+        # each row's segment [lo, hi): hi is where row + 1's would start
+        bounds = jnp.searchsorted(dst_s, jnp.concatenate([rows, rows + 1]))
+        lo, hi = bounds[:R], bounds[R:]
+        at = lo[:, None] + jnp.arange(r_cap, dtype=lo.dtype)
+        found = src_s[jnp.minimum(at, dst_s.shape[0] - 1)]
+        return jnp.where(at < hi[:, None], found, rows[:, None])
+
+
+def reverse_neighbors(knn_idx: jax.Array, r_cap: int) -> jax.Array:
+    """(N, r_cap) reverse adjacency of every row (``reverse_rows`` over
+    all of them)."""
+    return reverse_rows(knn_idx, jnp.arange(knn_idx.shape[0],
+                                            dtype=jnp.int32), r_cap)
 
 
 def _neighbors_of(knn_idx, nbrs):
@@ -63,14 +80,15 @@ def _neighbors_of(knn_idx, nbrs):
     return knn_idx[nbrs[:, r], c]
 
 
-def _tile_explore(x, knn_idx, knn_dist, rev, rows, key, sample: int):
-    """One tile of nodes; returns merged (idx (T,K), dist (T,K))."""
+def _tile_explore(x, knn_idx, knn_dist, rows, rev, key, sample: int):
+    """One tile of nodes with their (T, r_cap) reverse neighbours
+    ``rev``; returns merged (idx (T,K), dist (T,K))."""
     T = rows.shape[0]
     K = knn_idx.shape[1]
     with spans.scope("explore.gather"):
         nbrs = knn_idx[rows]                              # (T, K)
         fwd = _neighbors_of(knn_idx, nbrs)                # neighbors' nbrs
-        cand = jnp.concatenate([fwd, rev[rows]], axis=1)
+        cand = jnp.concatenate([fwd, rev], axis=1)
         if sample and sample < cand.shape[1]:
             cols = jax.random.randint(key, (T, sample), 0, cand.shape[1])
             cand = jnp.take_along_axis(cand, cols, axis=1)
@@ -172,65 +190,57 @@ def sharded_explore_round(x_loc, ids_loc, knn_idx_loc, knn_dist_loc, *,
         return knn_lib.merge_candidates(ids, ds, K, self_idx=ids_loc)
 
 
+def _explore_tiles(x, knn_idx, knn_dist, rows, ikey, *, sample: int,
+                   tile: int, r_cap: int):
+    """Explore ``rows`` against the whole graph: merged (ids, dists) of
+    shape (R, K), one per row.
+
+    Rows pad to a tile multiple by repeating the first row; padded
+    results are sliced off.  The reverse lists are built for the padded
+    rows alone (``reverse_rows``) and each tile takes its slice of them
+    with its rows, under ``jax.lax.map``."""
+    K = knn_idx.shape[1]
+    R = rows.shape[0]
+    n_tiles = -(-R // tile)
+    with spans.scope("explore.gather"):
+        rows_p = jnp.concatenate(
+            [rows, jnp.broadcast_to(rows[:1], (n_tiles * tile - R,))])
+        tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
+            jnp.arange(n_tiles))
+    rev = reverse_rows(knn_idx, rows_p, r_cap)
+
+    def one(args):
+        r, rv, tk = args
+        return _tile_explore(x, knn_idx, knn_dist, r, rv, tk, sample)
+
+    ti, td = jax.lax.map(one, (rows_p.reshape(n_tiles, tile),
+                               rev.reshape(n_tiles, tile, r_cap), tkeys))
+    with spans.scope("explore.writeback"):
+        return ti.reshape(-1, K)[:R], td.reshape(-1, K)[:R]
+
+
 @functools.partial(jax.jit, static_argnames=("sample", "tile", "r_cap"))
 def _explore_round(x, knn_idx, knn_dist, ikey, *, sample: int, tile: int,
                    r_cap: int):
-    """One full exploring iteration as ONE device dispatch.
-
-    ``reverse_neighbors`` is hoisted inside (it reads the same graph every
-    tile), and the row tiles run under ``jax.lax.map`` — the
-    ``brute_force_knn`` pattern — instead of the old per-tile Python loop
-    that paid ``n_tiles`` dispatches (plus one for the reverse pass) per
-    iteration.  Rows pad to a tile multiple with row 0 (same key stream
-    and padding as the old loop, so trajectories are unchanged); padded
-    rows never survive the final slice.
-    """
-    N, K = knn_idx.shape
-    n_tiles = -(-N // tile)
-    rev = reverse_neighbors(knn_idx, r_cap)
-    with spans.scope("explore.gather"):
-        rows = jnp.arange(N, dtype=jnp.int32)
-        rows = jnp.concatenate(
-            [rows, jnp.zeros((n_tiles * tile - N,), jnp.int32)])
-        tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
-            jnp.arange(n_tiles))
-
-    def one(args):
-        r, tk = args
-        return _tile_explore(x, knn_idx, knn_dist, rev, r, tk, sample)
-
-    ti, td = jax.lax.map(one, (rows.reshape(n_tiles, tile), tkeys))
-    with spans.scope("explore.writeback"):
-        return ti.reshape(-1, K)[:N], td.reshape(-1, K)[:N]
+    """One full exploring iteration as ONE device dispatch: every row,
+    its row tiles under ``jax.lax.map`` (the ``brute_force_knn`` pattern)
+    rather than one dispatch per tile.  Rows pad to a tile multiple with
+    row 0; padded rows never survive the final slice."""
+    rows = jnp.arange(knn_idx.shape[0], dtype=jnp.int32)
+    return _explore_tiles(x, knn_idx, knn_dist, rows, ikey, sample=sample,
+                          tile=tile, r_cap=r_cap)
 
 
 @functools.partial(jax.jit, static_argnames=("sample", "tile", "r_cap"))
 def _explore_rows_round(x, knn_idx, knn_dist, rows, ikey, *, sample: int,
                         tile: int, r_cap: int):
     """One exploring iteration over a SUBSET of rows (incremental graph
-    maintenance after ``transform.knn_insert``): the same per-tile body as
+    maintenance after ``transform.knn_insert``): the same tiles as
     ``_explore_round``, but only ``rows`` are explored and written back —
-    O(len(rows)) work against the full graph's reverse adjacency.  Rows
-    pad to a tile multiple by repeating the first row; padded results are
-    sliced off before the scatter."""
-    _, K = knn_idx.shape
-    R = rows.shape[0]
-    n_tiles = -(-R // tile)
-    rev = reverse_neighbors(knn_idx, r_cap)
-    with spans.scope("explore.gather"):
-        rows_p = jnp.concatenate(
-            [rows, jnp.broadcast_to(rows[:1], (n_tiles * tile - R,))])
-        tkeys = jax.vmap(lambda t: jax.random.fold_in(ikey, t))(
-            jnp.arange(n_tiles))
-
-    def one(args):
-        r, tk = args
-        return _tile_explore(x, knn_idx, knn_dist, rev, r, tk, sample)
-
-    ti, td = jax.lax.map(one, (rows_p.reshape(n_tiles, tile), tkeys))
+    one sort of the graph, then O(len(rows)) work."""
+    ti, td = _explore_tiles(x, knn_idx, knn_dist, rows, ikey, sample=sample,
+                            tile=tile, r_cap=r_cap)
     with spans.scope("explore.writeback"):
-        ti = ti.reshape(-1, K)[:R]
-        td = td.reshape(-1, K)[:R]
         return knn_idx.at[rows].set(ti), knn_dist.at[rows].set(td)
 
 

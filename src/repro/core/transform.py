@@ -23,11 +23,11 @@ operations on top of a fitted model, reusing the batch machinery:
 * :func:`knn_insert` — grow the (N, K) KNN graph by Q new points without
   a rebuild.  New rows get one streaming top-k against the corpus merged
   (``knn.merge_candidates``) with a query-vs-query top-k; existing rows
-  adopt new points through a reverse-candidate scatter (the
-  ``neighbor_explore.reverse_neighbors`` sorted-scatter pattern, carrying
-  distances along); then ``neighbor_explore(rows=touched)`` repairs only
-  the affected rows through the standard exploring machinery.  Recall
-  against a fresh build is pinned in tests/test_transform.py.
+  adopt new points through a reverse-candidate scatter (sorted by
+  destination, carrying distances along); then
+  ``neighbor_explore(rows=touched)`` repairs only the affected rows
+  through the standard exploring machinery.  Recall against a fresh build
+  is pinned in tests/test_transform.py.
 
 Both entry points are wrapped by the :class:`repro.LargeVis` estimator
 (``transform`` / ``insert``); the continuous-batching projection server
@@ -174,10 +174,10 @@ def project(x_new, *, x, y, key=None, cfg: LargeVisConfig | None = None,
 def _reverse_candidates(dst, src, dist, n: int, r_cap: int):
     """Scatter directed candidate edges (src -> dst) into per-``dst`` slots.
 
-    The ``neighbor_explore.reverse_neighbors`` sorted-scatter (sort by
-    destination, rank within segment, cap at ``r_cap``), extended to carry
-    the candidate distance along.  Unfilled slots hold the row's own index
-    at INF distance — inert under ``merge_candidates``."""
+    A sorted scatter (sort by destination, rank within segment, cap at
+    ``r_cap``) that carries the candidate distance along.  Unfilled slots
+    hold the row's own index at INF distance — inert under
+    ``merge_candidates``."""
     e = dst.shape[0]
     order = jnp.argsort(dst)
     dst_s, src_s, d_s = dst[order], src[order], dist[order]

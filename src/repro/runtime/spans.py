@@ -20,7 +20,7 @@
 Scopes and spans:
 
 =========================  ====================================================
-``lv.explore.reverse``     the reverse adjacency (``reverse_neighbors``)
+``lv.explore.reverse``     the explored rows' reverse lists (``reverse_rows``)
 ``lv.explore.gather``      candidate ids, ``x[cand]``, squared distances, tile
                            padding and keys
 ``lv.explore.merge``       the argsort-dedup top-K (``merge_candidates``)

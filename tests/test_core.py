@@ -9,8 +9,11 @@ from repro.core import knn as knn_lib
 from repro.core import metrics, perplexity
 from repro.core import sampler as sampler_lib
 from repro.core.largevis import largevis
-from repro.core.neighbor_explore import neighbor_explore, reverse_neighbors
+from repro.core.neighbor_explore import (_explore_rows_round, neighbor_explore,
+                                         reverse_neighbors, reverse_rows)
 from repro.data.synthetic import gaussian_mixture
+
+import hlo_checks
 
 KEY = jax.random.key(0)
 
@@ -147,6 +150,67 @@ def test_reverse_neighbors_contains_true_reverse():
     rev = reverse_neighbors(idx, 4)
     # node 0 is listed by 1, 2, 3
     assert {1, 2, 3} <= set(np.asarray(rev[0]).tolist())
+
+
+def _skewed_graph(n, k, seed=0):
+    """(n, k) ids drawn Zipf-skewed: a few rows are named far more than k
+    times, many never."""
+    rng = np.random.default_rng(seed)
+    hot = (rng.zipf(1.4, (n, k)) - 1) % n
+    return np.where(rng.random((n, k)) < 0.7, hot,
+                    rng.integers(0, n, (n, k))).astype(np.int32)
+
+
+def _reverse_lists_np(idx, rows, r_cap):
+    """For each row r of ``rows``: the first ``r_cap`` sources i whose
+    list holds r, in ascending i (once per mention), padded with r."""
+    out = np.repeat(np.asarray(rows, np.int32)[:, None], r_cap, axis=1)
+    for j, r in enumerate(rows):
+        src = np.nonzero(idx == r)[0][:r_cap]
+        out[j, :src.shape[0]] = src
+    return out
+
+
+@pytest.mark.parametrize("r_cap", [4, 10, 16])
+@pytest.mark.parametrize("kind", ["all", "contiguous", "wrapping",
+                                  "repeats"])
+def test_reverse_rows_match_definition(kind, r_cap):
+    """The reverse lists, for every row (``reverse_neighbors``) or a block
+    of rows (``reverse_rows``), are exactly the first r_cap sources in
+    ascending order padded with self, also for rows whose in-degree
+    exceeds r_cap (the last slot of those is a real source, not self)."""
+    n, k = 500, 10
+    idx = _skewed_graph(n, k)
+    indeg = np.bincount(idx.ravel(), minlength=n)
+    assert (indeg > r_cap).sum() >= 5
+    rows = {"all": np.arange(n),
+            "contiguous": np.arange(120, 184),
+            "wrapping": (n - 25 + np.arange(60)) % n,
+            "repeats": np.concatenate([np.argsort(-indeg)[:8], [0, 0, 7],
+                                       np.argsort(-indeg)[:8]]),
+            }[kind].astype(np.int32)
+    if kind == "all":
+        got = reverse_neighbors(jnp.asarray(idx), r_cap)
+    else:
+        got = reverse_rows(jnp.asarray(idx), jnp.asarray(rows), r_cap)
+    assert got.shape == (rows.shape[0], r_cap)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _reverse_lists_np(idx, rows, r_cap))
+
+
+def test_explore_rows_round_holds_no_full_reverse_table():
+    """The rows round builds reverse lists for its rows only: no (N, r_cap)
+    table of ids over the whole graph is in its program."""
+    n, k, d, r_cap = 211, 6, 16, 5
+    x = jax.random.normal(jax.random.key(5), (n, d), jnp.float32)
+    idx = jnp.asarray(_skewed_graph(n, k, seed=1))
+    dist = jnp.ones((n, k), jnp.float32)
+    rows = jnp.arange(24, dtype=jnp.int32)
+    text = _explore_rows_round.lower(x, idx, dist, rows, jax.random.key(0),
+                                     sample=0, tile=8,
+                                     r_cap=r_cap).as_text()
+    hlo_checks.assert_has_op(text, "sort", what="one sort of the graph")
+    hlo_checks.assert_no_buffer(text, (n, r_cap), what="full reverse table")
 
 
 def test_perplexity_calibration(blobs):

@@ -10,7 +10,7 @@ import pytest
 from repro import LargeVis, LargeVisConfig
 from repro.core import knn as knn_lib
 from repro.core import transform as tr
-from repro.core.neighbor_explore import neighbor_explore
+from repro.core.neighbor_explore import _explore_round, neighbor_explore
 from repro.data.synthetic import mnist_like
 from repro.kernels import ops, ref
 
@@ -201,6 +201,36 @@ def test_neighbor_explore_rows_subset(data):
     assert np.array_equal(np.asarray(idx2)[untouched],
                           np.asarray(idx)[untouched])
     assert float(jnp.mean(dist2[bad])) <= float(jnp.mean(dist[bad]))
+
+
+def test_explore_rows_match_full_round():
+    """Exploring a block of rows (wrapping at N, with a repeat) gives on
+    those rows the same (ids, dists) as one full round over every row
+    from the same graph, and leaves the other rows alone.  The graph is
+    skewed, so some rows are named by more than K others."""
+    n, k, d = 211, 6, 16
+    rng = np.random.default_rng(1)
+    hot = (rng.zipf(1.4, (n, k)) - 1) % n
+    idx = np.where(rng.random((n, k)) < 0.7, hot, rng.integers(0, n, (n, k)))
+    idx = np.where(idx == np.arange(n)[:, None], (idx + 1) % n, idx)
+    assert (np.bincount(idx.ravel(), minlength=n) > k).sum() >= 5
+    x = jax.random.normal(jax.random.key(5), (n, d), jnp.float32)
+    idx = jnp.asarray(idx, jnp.int32)
+    diff = x[idx] - x[:, None, :]
+    dist = jnp.sum(diff * diff, axis=-1)
+    key = jax.random.key(9)
+    full_i, full_d = _explore_round(x, idx, dist, key, sample=0, tile=64,
+                                    r_cap=k)
+    rows = jnp.asarray(np.r_[(n - 30 + np.arange(70)) % n, 3], jnp.int32)
+    sub_i, sub_d = neighbor_explore(x, idx, dist, iters=1, sample=0, key=key,
+                                    tile=16, rows=rows)
+    np.testing.assert_array_equal(np.asarray(sub_i[rows]),
+                                  np.asarray(full_i[rows]))
+    np.testing.assert_array_equal(np.asarray(sub_d[rows]),
+                                  np.asarray(full_d[rows]))
+    rest = np.setdiff1d(np.arange(n), np.asarray(rows))
+    np.testing.assert_array_equal(np.asarray(sub_i)[rest],
+                                  np.asarray(idx)[rest])
 
 
 def test_estimator_insert_grows_model(data, fitted):
