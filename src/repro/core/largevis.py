@@ -29,11 +29,11 @@ so progress can be observed mid-layout.
 
 The stage-1 -> stage-2 hand-off is device-resident: with
 ``cfg.sampler_impl`` ``"device"``/``"auto"`` the alias tables are built
-by a jitted sort/prefix-sum construction (`core/sampler.py`) directly
-from the device graph, and the samplers flow into every layout driver
-as JAX pytrees — no host materialization of ``idx``/``weights`` between
-the stages, which is what keeps the boundary O(E log E) on device
-instead of minutes of single-core Vose at the paper's E = 150M.
+by a jitted, exact fixed-point prefix-sum construction (`core/sampler.py`)
+directly from the device graph, and the samplers flow into every layout
+driver as JAX pytrees — no host materialization of ``idx``/``weights``
+between the stages, which keeps the boundary on device instead of
+minutes of single-core Vose at the paper's E = 150M.
 """
 from __future__ import annotations
 

@@ -33,6 +33,7 @@ from repro.core.layout_engine import sgd_edge_step
 from repro.core.sampler import (EdgeSampler, NodeSampler,
                                 ShardedEdgeSampler, ShardedNodeSampler)
 from jax import shard_map
+from repro.kernels import ops
 from repro.runtime import spans
 from repro.runtime.fault_tolerance import (DegradedModeWarning,
                                            DivergenceWarning, InjectedFault,
@@ -519,6 +520,11 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
 
             guard.set_save_fn(_preempt_save)
         noted = set()       # (chunk length, route) whose signature is noted
+        # row tiles of y each step's kernel runs (the dispatch span's count)
+        slabs = ops.edge_step_slabs(
+            n_nodes, cfg.out_dim, batch, cfg.n_negatives,
+            layout_step=(kwargs["layout_step"]
+                         if cfg.prob_fn == "inv_quadratic" else "split"))
 
         def chunk(y, step_ids, t_fracs):
             args = (y, kr, step_ids, t_fracs)
@@ -532,7 +538,8 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
         try:
             while t < steps:
                 h = min(H, steps - t)
-                with spans.span("layout.dispatch", steps=h) as dispatch:
+                with spans.span("layout.dispatch", steps=h,
+                                slabs=slabs) as dispatch:
                     step_ids = jnp.arange(t, t + h, dtype=jnp.int32)
                     # host-side t/steps (f64 rounded to f32) — bit-identical
                     # to the Python loop's jnp.float32(t / steps) schedule
@@ -553,6 +560,7 @@ def run_layout(key, edge_sampler: EdgeSampler, neg_sampler: NodeSampler,
                                 "layout_step", "fused", "split", e),
                                 stacklevel=2)
                             kwargs["layout_step"] = "split"
+                            slabs = 1
                             y = chunk(jnp.asarray(y_backup), step_ids,
                                       t_fracs)
                     else:

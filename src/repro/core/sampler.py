@@ -8,29 +8,33 @@ as *binary*, so divergent edge weights never enter the gradient.
 Table construction comes in two implementations, selected by ``impl=``
 (``LargeVisConfig.sampler_impl`` at the pipeline level):
 
-* ``"device"`` (the ``"auto"`` default) — :func:`build_alias_device`, a
-  fully-jitted construction: stable-partition the scaled probabilities
-  into smalls (< 1) and larges (>= 1) with cumsum ranks (no sort — the
-  pairing below only needs the two groups in *some* fixed order, so the
-  build is O(E) data movement plus O(E log E) binary searches), then
-  resolve Vose's two-pointer pairing with prefix sums + ``searchsorted``
-  — smalls alias to the first large whose cumulative surplus covers
-  their cumulative deficit, and the boundary-straddling remainders flow
-  between adjacent larges through a backward alias chain, which makes
-  the per-slot marginals *exact* in exact arithmetic.  The cumulative
-  arithmetic runs in f64 via a trace-scoped ``enable_x64`` on CPU/GPU
-  (f32 prefix sums break down around E ~ 1e5 — see ``_alias_pairing``),
-  falling back to f32 on TPU.  No per-edge Python iteration, no host
-  round trip: stage-1 outputs stay device-resident all the way into the
-  layout step.
+* ``"device"`` (the ``"auto"`` default) — :func:`_alias_tables`, one
+  jitted construction, the same on every backend.  Masses are quantized
+  to fixed point, ``SLOT`` = 2^24 units a slot, and corrected to sum to
+  exactly E slots; Vose's pairing is then resolved by prefix sums and
+  binary searches in 64-bit integers (under a trace-scoped
+  ``enable_x64``): smalls alias the first large whose cumulative surplus
+  covers their cumulative deficit, and the boundary-straddling
+  remainders flow between consecutive larges through a backward alias
+  chain.  The integer pairing is exact, and a float32 threshold holds
+  each slot's units exactly, so the table draws slot i with probability
+  q_i / (E * 2^24): within 2^-24 of its weight's share, relative, and a
+  unit or two absolute (total variation ~3e-8 at E ~ 1e6).  The build
+  walks the graph in chunks of rows with carried chunk offsets, so
+  beyond its (E,) outputs it holds one chunk's temporaries.  No host
+  round trip: stage-1 outputs stay device-resident into the layout step.
+  Each device build runs inside a host span, ``lv.sampler.edge``
+  (``edges=``) or ``lv.sampler.node`` (``nodes=``), closed once its
+  tables are ready; its device scopes are ``lv.alias.quantize``,
+  ``lv.alias.scan`` (chunk offsets) and ``lv.alias.pair``.
 * ``"host"`` — :func:`build_alias`, the classic numpy Vose loop.  O(E)
   but single-core Python (minutes at the paper's E = N*K = 150M); kept as
   the test oracle and debug path.
 
 The produced (threshold, alias) tables differ between implementations —
-any table with the right per-index marginals is a valid alias table — but
-both are exact, and ``tests/test_sampler.py`` pins the device builder's
-marginals against the Vose oracle via threshold/alias reconstruction.
+any table with the right per-index marginals is a valid alias table —
+and ``tests/test_sampler.py`` pins the device builder's marginals against
+float64 targets and the Vose oracle via threshold/alias reconstruction.
 
 :class:`EdgeSampler` / :class:`NodeSampler` are registered JAX pytrees, so
 whole samplers thread through ``jit`` / ``lax.scan`` / ``shard_map`` as
@@ -38,7 +42,7 @@ single arguments (see ``core/layout_engine.py``).
 
 Distributed mode (:func:`build_samplers_sharded`) builds **per-shard**
 tables on the same 1-D "data" mesh the KNN ring and the perplexity
-stages use: each shard runs :func:`_alias_pairing` over its own rows'
+stages use: each shard runs :func:`_alias_tables` over its own rows'
 edges (local alias indices — a slab sliced out of a *global* table
 would carry alias pointers outside the slab and be invalid), negative
 degrees are completed with one ``psum`` of O(N) scatter partials, and a
@@ -52,7 +56,6 @@ shard draw and reproduce the flat samplers' key streams bitwise.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 
@@ -62,7 +65,8 @@ import numpy as np
 
 from jax import shard_map
 
-from repro.core.flat import ravel_rows, row_major
+from repro.core.flat import row_major
+from repro.runtime import spans
 
 
 def build_alias(probs: np.ndarray):
@@ -94,112 +98,303 @@ def build_alias(probs: np.ndarray):
     return threshold.astype(np.float32), alias
 
 
-def _alias_pairing(probs: jax.Array, *, hi_dtype=jnp.float32):
-    """Traced alias-table construction body.  probs: (n,) nonnegative, any
-    scale (all-zero input falls back to uniform).  Returns
-    (threshold (n,) f32, alias (n,) i32) with exact per-index marginals.
+# fixed point of the device build: a slot holds 2**24 units of mass, so a
+# threshold (units kept / 2**24) is exact in float32
+SLOT = 1 << 24
+# elements per chunk of the device build's loops
+CHUNK = 1 << 20
+# the first quantization aims this far below the total, so that it never
+# passes it; the second fills the gap the same way
+_MARGIN = 1.0 - 2.0 ** -16
+_I64, _I32, _F32 = jnp.int64, jnp.int32, jnp.float32
+_TOP = (1 << 63) - 1           # pads the search tree's rows
+_QUERIES = 8192                # queries of the search tree read at once
 
-    Construction (fully vectorized — cumsum/searchsorted/scatter, zero
-    host involvement): stable-partition the scaled probabilities so the
-    smalls (s < 1, deficit d = 1-s) occupy a prefix and the larges
-    (s >= 1, surplus e = s-1) a suffix.  The partition is cumsum ranks,
-    NOT a sort — Vose's pairing works for the two groups in any fixed
-    order, since the prefix arrays below are monotone by construction.
-    The pairing becomes:
 
-    * small i aliases the first large j whose cumulative surplus SE_j
-      reaches the cumulative deficit D_i (one ``searchsorted``);
-    * a small straddling a surplus boundary is charged wholly to the later
-      large, so larges <= j under-collect by beta_{j+1} = SE_j - D_{last
-      small with D <= SE_j}; large j+1 repays exactly that by keeping only
-      threshold 1 - beta_{j+1} of its own slot and aliasing the remainder
-      to large j (a backward chain over the partitioned larges).
+def _scan(v, op, identity):
+    """Inclusive prefix of the 1-D ``v`` under ``op`` (``jnp.add`` or
+    ``jnp.maximum``): 128-lane rows by log-step shifts, rows by recursion.
+    (The TPU compiler takes tens of seconds over a 1-D ``cumsum``.)"""
+    n = v.shape[0]
+    m = -(-n // 128)
+    x = jnp.pad(v, (0, m * 128 - n), constant_values=identity)
+    x = x.reshape(m, 128)
+    s = 1
+    while s < 128:
+        x = op(x, jnp.pad(x[:, :-s], ((0, 0), (s, 0)),
+                          constant_values=identity))
+        s *= 2
+    if m > 1:
+        rows = _scan(x[:, -1], op, identity)
+        x = op(x, jnp.concatenate([jnp.full((1,), identity, v.dtype),
+                                   rows[:-1]])[:, None])
+    return x.reshape(-1)[:n]
 
-    Telescoping the chain gives every index its exact target mass; the
-    final boundary term is total-surplus - total-deficit = 0, so nothing
-    is lost.  Ties, zero-surplus larges, zero probabilities, and n == 1
-    all degenerate correctly (clamps only guard rounding).
 
-    ``hi_dtype`` is the cumulative-arithmetic dtype.  The prefix sums
-    reach magnitude ~n with sub-1.0 increments, and beta is a
-    catastrophically-cancelling difference of two such prefixes — in f32
-    the per-slot *relative* marginal error passes 100% around E ~ 1e5.
-    :func:`_pairing_scope` therefore runs this in f64 wherever the
-    backend supports it (CPU/GPU), keeping f32 only as the TPU fallback.
+def _below(a, v):
+    """For each query of ``v``: how many entries of the sorted 1-D ``a``
+    lie below it, and the largest of them (-1 where none).
+
+    A search tree of 128-wide rows (each level holds the last entry of
+    each row below it): a query reads one row a level, compares it whole,
+    and goes down to the first row that does not lie wholly below it.  Two
+    or three row reads a query, where a binary search makes ~20 dependent
+    reads of single entries, which the chip serves slowly."""
+    levels = [a]
+    while levels[-1].shape[0] > 128:
+        x = levels[-1]
+        m = -(-x.shape[0] // 128)
+        x = jnp.pad(x, (0, m * 128 - x.shape[0]), constant_values=_TOP)
+        levels[-1] = x.reshape(m, 128)
+        levels.append(x.reshape(m, 128)[:, -1])
+    top = levels.pop()
+    top = jnp.pad(top, (0, 128 - top.shape[0]), constant_values=_TOP)
+
+    def walk(v):
+        below = top < v
+        count = jnp.sum(below, dtype=_I32)
+        best = jnp.max(jnp.where(below, top, -1))
+        for rows in reversed(levels):
+            at = jnp.minimum(count, rows.shape[0] - 1)
+            row = rows[at]
+            below = row < v
+            count = at * 128 + jnp.sum(below, dtype=_I32)
+            best = jnp.maximum(best, jnp.max(jnp.where(below, row, -1)))
+        return count, best
+
+    # queries in blocks: the rows a block reads stay a few MB
+    return jax.lax.map(walk, v, batch_size=_QUERIES)
+
+
+def _window(a_t, start, rows: int):
+    """Rows ``start .. start + rows`` of a 2-D array, flattened row-major,
+    from its transpose ``a_t``: the chip holds a narrow 2-D array
+    column-major, and slicing rows would first copy all of it row-major."""
+    slab = jax.lax.dynamic_slice_in_dim(a_t, start, rows, axis=1)
+    r, c = row_major(rows, a_t.shape[0])
+    return slab[c, r]
+
+
+def _flatten(a):
+    """``a.reshape(-1)`` of a narrow 2-D array, a block of rows at a time
+    (:func:`_window`), so that no index array the size of ``a`` is held."""
+    n_rows, k = a.shape
+    rows = _chunk_rows(n_rows, k)
+    a_t = a.T
+
+    def block(c, out):
+        start = jnp.minimum(c * rows, n_rows - rows)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _window(a_t, start, rows), start * k, 0)
+
+    return jax.lax.fori_loop(0, -(-n_rows // rows), block,
+                             jnp.zeros(n_rows * k, a.dtype))
+
+
+def _in_degrees(knn_idx, w, size: int):
+    """Sum of the weights ``max(w, 0)`` landing on each of ``size`` nodes
+    by ``knn_idx``, added a block of rows at a time in row-major order."""
+    n_rows, k = knn_idx.shape
+    rows = _chunk_rows(n_rows, k)
+    idx_t, w_t = knn_idx.T, w.astype(jnp.float32).T
+
+    def block(c, deg):
+        start = jnp.minimum(c * rows, n_rows - rows)
+        own = start + jnp.arange(rows, dtype=jnp.int32) >= c * rows
+        ws = jnp.where(jnp.repeat(own, k),
+                       jnp.maximum(_window(w_t, start, rows), 0.0), 0.0)
+        return deg.at[_window(idx_t, start, rows)].add(ws)
+
+    return jax.lax.fori_loop(0, -(-n_rows // rows), block,
+                             jnp.zeros((size,), jnp.float32))
+
+
+def _chunk_rows(n_rows: int, k: int) -> int:
+    """Rows of a block of about ``CHUNK`` entries."""
+    return min(max(1, CHUNK // k), n_rows)
+
+
+def _alias_tables(p: jax.Array):
+    """Exact alias table over the 1-D masses ``p``.
+
+    p: (n,) nonnegative, any scale (all zero: uniform).  Returns
+    (threshold (n,) f32, alias (n,) i32, total f32 ~ sum(p)).  Traced
+    under ``jax.enable_x64``: the sums are 64-bit integers.
+
+    Quantize: slot i gets q_i units, q_i = floor(p_i s1) + floor(p_i s2)
+    + an even share of what is left, so that the q sum to exactly
+    n * SLOT.  s1 aims 2^-16 below the total, s2 fills that gap the same
+    way, and the last residue (under about one unit a slot) goes in whole
+    units to the nonzero slots in order.  The table draws slot i with
+    probability q_i / (n SLOT): within 2^-24 of p_i / sum(p) relative, and
+    a unit or two absolute.
+
+    Pair (Vose by prefix sums, all in integers): smalls (q < SLOT) owe
+    d = SLOT - q, larges have surplus e = q - SLOT, D and SE are their
+    prefix sums in index order.  A small keeps its own q and aliases the
+    first large whose SE reaches its D.  A large j keeps SLOT - beta_j and
+    aliases the previous large, beta_j being the part of the small
+    straddling SE_{j-1} that was charged to a later large: SE_{j-1} minus
+    the last D at or below it.  The sums telescope to q_j for every large.
+
+    Memory: beyond its inputs and outputs the build holds one chunk at a
+    time.  Its loops run over chunks of ``CHUNK`` slots in index order
+    (the last one's window shifted back to end at n); chunk-level starts
+    of the prefix sums, of the nonzero count and of the last large carry
+    the rest.  Each small's search runs over the chunks whose SE range
+    holds its D, each large's over the chunks whose D range holds its
+    SE_{j-1}: both monotone, so each pass visits under three chunks per
+    chunk.
     """
-    p = jnp.asarray(probs, jnp.float32).reshape(-1).astype(hi_dtype)
     n = p.shape[0]
-    one = jnp.asarray(1.0, hi_dtype)
-    zero = jnp.zeros((), hi_dtype)
-    p = jnp.maximum(p, zero)
-    total = jnp.sum(p)
-    p = jnp.where(total > 0, p, jnp.ones_like(p))
-    total = jnp.where(total > 0, total, jnp.asarray(n, hi_dtype))
-    scaled = p * (n / total)
+    if n >= 2 ** 31:
+        raise ValueError(f"{n} entries: alias indices are int32")
+    width = min(CHUNK, n)
+    nc = -(-n // width)
+    units = n * SLOT
 
-    # stable partition, smalls first: O(n) cumsum ranks + one scatter
-    is_small = scaled < one
-    m = jnp.sum(is_small.astype(jnp.int32))      # partition point / first
-    rank_small = jnp.cumsum(is_small.astype(jnp.int32)) - 1       # large
-    rank_large = m + jnp.cumsum((~is_small).astype(jnp.int32)) - 1
-    dest = jnp.where(is_small, rank_small, rank_large)
-    order = jnp.zeros(n, jnp.int32).at[dest].set(
-        jnp.arange(n, dtype=jnp.int32))          # partitioned -> original
-    ss = scaled[order]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    small = pos < m
-    d = jnp.where(small, one - ss, zero)         # deficits  (small prefix)
-    e = jnp.where(small, zero, ss - one)         # surpluses (large suffix)
-    D = jnp.cumsum(d)
-    SE = jnp.cumsum(e)
+    with spans.scope("alias.quantize"):
+        p = p.astype(_F32)
+        t0 = jnp.sum(jnp.maximum(p, 0.0))
+        uniform = ~(t0 > 0)
+        t0 = jnp.where(uniform, jnp.float32(n), t0)
 
-    # smalls -> first large whose cumulative surplus covers their deficit
-    tgt = jnp.clip(jnp.searchsorted(SE, D, side="left").astype(jnp.int32),
-                   m, n - 1)
-    # larges: beta_j = straddling deficit owed to earlier larges, repaid by
-    # this slot's alias pointing at the previous large
-    prev_se = SE - e                             # SE_{j-1}
-    hi = jnp.searchsorted(D, prev_se, side="right").astype(jnp.int32) - 1
-    covered = jnp.where(hi >= 0, D[jnp.clip(hi, 0, n - 1)], zero)
-    beta = jnp.clip(prev_se - covered, 0.0, 1.0)
+        def clean(x):
+            return jnp.where(uniform, 1.0, jnp.maximum(x, 0.0))
 
-    thr_sorted = jnp.where(small, ss, one - beta).astype(jnp.float32)
-    alias_sorted = jnp.where(small, order[tgt],
-                             order[jnp.clip(pos - 1, m, n - 1)])
-    threshold = jnp.zeros(n, jnp.float32).at[order].set(thr_sorted)
-    alias = jnp.zeros(n, jnp.int32).at[order].set(
-        alias_sorted.astype(jnp.int32))
-    return threshold, alias
+        def floor_sum(scale):
+            return jnp.sum(jnp.floor(clean(p) * scale).astype(_I64))
+
+        # q0 is exact, so it corrects any error of the float32 sum t0
+        s0 = jnp.float32(units) / t0
+        q0 = floor_sum(s0)
+        s1 = s0 * (jnp.float32(units) / q0.astype(_F32)) * _MARGIN
+        q1 = floor_sum(s1)
+        s2 = s1 * ((units - q1).astype(_F32) / q1.astype(_F32)) * _MARGIN
+        rest = units - q1 - floor_sum(s2)
+        nnz = jnp.sum((clean(p) > 0).astype(_I64))
+        base, extra = rest // nnz, rest % nnz
+        total = q1.astype(_F32) / s1
+
+    def start_of(c):
+        return jnp.minimum(c * width, n - width)
+
+    def quanta(c, nz_before):
+        """Chunk c's window: units q, flat positions, smalls, larges and
+        its count of nonzero slots."""
+        with spans.scope("alias.quantize"):
+            start = start_of(c)
+            x = clean(jax.lax.dynamic_slice_in_dim(p, start, width))
+            pos = start + jnp.arange(width, dtype=_I32)
+            own = pos >= c * width
+            nz = (own & (x > 0)).astype(_I64)
+            rank = nz_before + _scan(nz, jnp.add, 0) - nz
+            q = (jnp.floor(x * s1).astype(_I64)
+                 + jnp.floor(x * s2).astype(_I64)
+                 + nz * (base + (rank < extra)))
+        return q, pos, own & (q < SLOT), own & (q >= SLOT), jnp.sum(nz)
+
+    def deficits(q, small):
+        return jnp.where(small, SLOT - q, 0)
+
+    def surpluses(q, large):
+        return jnp.where(large, q - SLOT, 0)
+
+    # chunk starts: nonzero slots, D, SE, last large before the chunk
+    def starts_of(c, carry):
+        nz0, d0, e0, last, table = carry
+        table = table.at[:, c].set(jnp.stack([nz0, d0, e0, last]))
+        q, pos, small, large, nz = quanta(c, nz0)
+        return (nz0 + nz, d0 + jnp.sum(deficits(q, small)),
+                e0 + jnp.sum(surpluses(q, large)),
+                jnp.maximum(last, jnp.max(jnp.where(large, pos, -1)
+                                          ).astype(_I64)), table)
+
+    zero = jnp.zeros((), _I64)
+    with spans.scope("alias.scan"):
+        *ends, table = jax.lax.fori_loop(
+            _I32(0), _I32(nc), starts_of,
+            (zero, zero, zero, zero - 1, jnp.zeros((4, nc + 1), _I64)))
+        table = table.at[:, nc].set(jnp.stack(ends))
+    nz_start, d_start, e_start, last_large = table
+    d_end, e_end = d_start[1:], e_start[1:]
+
+    def span_of(ends_, lo, hi, side):
+        """Chunks whose range of ``ends_`` holds values lo..hi."""
+        c0, c1 = (jnp.minimum(jnp.searchsorted(ends_, v, side=side), nc - 1)
+                  for v in (lo, hi))
+        return c0.astype(_I32), c1.astype(_I32)
+
+    def write(tables, c, mask, thr, ali):
+        out = []
+        for arr, new in zip(tables, (thr, ali)):
+            old = jax.lax.dynamic_slice_in_dim(arr, start_of(c), width)
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                arr, jnp.where(mask, new, old), start_of(c), 0))
+        return tuple(out)
+
+    def smalls(c, tables):
+        """Chunk c's smalls: threshold q / SLOT, alias the first large
+        whose SE reaches their D."""
+        q, pos, small, _, _ = quanta(c, nz_start[c])
+        dd = d_start[c] + _scan(deficits(q, small), jnp.add, 0)
+
+        def find(cl, tgt):
+            ql, _, _, large, _ = quanta(cl, nz_start[cl])
+            se = e_start[cl] + _scan(surpluses(ql, large), jnp.add, 0)
+            at, _ = _below(se, dd)
+            mine = small & (dd > e_start[cl]) & (dd <= e_end[cl])
+            return jnp.where(mine, start_of(cl) + at, tgt)
+
+        c0, c1 = span_of(e_end, d_start[c] + 1, d_end[c], "left")
+        tgt = jax.lax.fori_loop(c0, c1 + 1, find, pos)
+        return write(tables, c, small, q.astype(_F32) / SLOT, tgt)
+
+    def larges(c, tables):
+        """Chunk c's larges: threshold 1 - beta / SLOT, alias the previous
+        large (itself for the first)."""
+        q, pos, _, large, _ = quanta(c, nz_start[c])
+        ee = surpluses(q, large)
+        prev_se = e_start[c] + _scan(ee, jnp.add, 0) - ee
+        seen = _scan(jnp.where(large, pos, -1), jnp.maximum, -1)
+        prev = jnp.maximum(last_large[c].astype(_I32),
+                           jnp.concatenate([jnp.full((1,), -1, _I32),
+                                            seen[:-1]]))
+
+        def find(cs, beta):
+            qs, _, small, _, _ = quanta(cs, nz_start[cs])
+            dd = d_start[cs] + _scan(deficits(qs, small), jnp.add, 0)
+            _, covered = _below(dd, prev_se + 1)     # the last D <= SE_{j-1}
+            mine = (large & (prev_se >= d_start[cs])
+                    & ((prev_se < d_end[cs]) | (cs == nc - 1)))
+            return jnp.where(mine,
+                             prev_se - jnp.maximum(covered, d_start[cs]),
+                             beta)
+
+        c0, c1 = span_of(d_end, e_start[c], e_end[c], "right")
+        beta = jax.lax.fori_loop(c0, c1 + 1, find, jnp.zeros_like(q))
+        thr = (SLOT - beta).astype(_F32) / SLOT
+        return write(tables, c, large, thr, jnp.where(prev >= 0, prev, pos))
+
+    with spans.scope("alias.pair"):
+        tables = (jnp.zeros(n, _F32), jnp.zeros(n, _I32))
+        tables = jax.lax.fori_loop(_I32(0), _I32(nc), smalls, tables)
+        thr, alias = jax.lax.fori_loop(_I32(0), _I32(nc), larges, tables)
+    return thr, alias, total
 
 
-_alias_jit = jax.jit(_alias_pairing, static_argnames=("hi_dtype",))
-
-
-def _pairing_scope():
-    """(context manager, dtype) for the pairing's cumulative arithmetic.
-
-    CPU/GPU: a trace-scoped ``enable_x64`` so the prefix sums run in f64
-    (exact marginals at any E) without requiring global x64 mode.  TPU
-    has no native f64, so it keeps the f32 construction — a KNOWN
-    LIMITATION: per-slot relative marginal error grows with E (~65% at
-    E=1e5, >100% at E>=1e6; past E ~ 1e7 the beta cancellation loses all
-    precision), so large-E TPU runs should build tables on the host CPU
-    platform (``sampler_impl="host"``, or a CPU-backed device build) until
-    a compensated-summation f32 pairing lands.  Builders enter this scope
-    at the top level and trace entirely under it; it must not nest inside
-    an outer non-x64 jit trace."""
-    if jax.default_backend() == "tpu":
-        return contextlib.nullcontext(), jnp.float32
-    return jax.enable_x64(True), jnp.float64
+@jax.jit
+def _alias_jit(p):
+    thr, alias, _ = _alias_tables(p)
+    return thr, alias
 
 
 def build_alias_device(probs) -> tuple:
-    """One jitted device computation: probs -> (threshold f32, alias i32).
-    See :func:`_alias_pairing` for the construction and dtype policy."""
-    scope, hi_dtype = _pairing_scope()
-    probs = jnp.asarray(probs)
-    with scope:
-        return _alias_jit(probs, hi_dtype=hi_dtype)
+    """One jitted device computation: probs (n,) -> (threshold f32,
+    alias i32), exact (see :func:`_alias_tables`)."""
+    probs = jnp.asarray(probs, jnp.float32).reshape(-1)
+    with jax.enable_x64(True):
+        return _alias_jit(probs)
 
 
 def sample_alias(key, threshold: jax.Array, alias: jax.Array, shape):
@@ -345,25 +540,22 @@ def _resolve_impl(impl: str) -> str:
     return "device" if impl == "auto" else impl
 
 
-@functools.partial(jax.jit, static_argnames=("hi_dtype",))
-def _build_edge_sampler_device(knn_idx, weights, *,
-                               hi_dtype=jnp.float32) -> EdgeSampler:
+@jax.jit
+def _build_edge_sampler_device(knn_idx, weights) -> EdgeSampler:
     N, K = knn_idx.shape
+    thr, alias, _ = _alias_tables(_flatten(weights))
     src = row_major(N, K)[0]
-    dst = ravel_rows(knn_idx).astype(jnp.int32)
-    thr, alias = _alias_pairing(ravel_rows(weights), hi_dtype=hi_dtype)
+    dst = _flatten(knn_idx).astype(jnp.int32)
     return EdgeSampler(src, dst, thr, alias, N * K)
 
 
-@functools.partial(jax.jit, static_argnames=("power", "hi_dtype"))
-def _build_negative_sampler_device(knn_idx, weights, *, power: float,
-                                   hi_dtype=jnp.float32) -> NodeSampler:
+@functools.partial(jax.jit, static_argnames=("power",))
+def _build_negative_sampler_device(knn_idx, weights, *,
+                                   power: float) -> NodeSampler:
     N, _ = knn_idx.shape
-    w = jnp.maximum(weights.astype(jnp.float32), 0.0)
-    deg = jnp.sum(w, axis=1)                              # out-degree
-    deg = deg.at[ravel_rows(knn_idx)].add(ravel_rows(w))  # + in-degree
-    thr, alias = _alias_pairing(jnp.maximum(deg, 1e-12) ** power,
-                                hi_dtype=hi_dtype)
+    deg = (jnp.sum(jnp.maximum(weights, 0.0), axis=1)         # out + in
+           + _in_degrees(knn_idx, weights, N))
+    thr, alias, _ = _alias_tables(jnp.maximum(deg, 1e-12) ** power)
     return NodeSampler(thr, alias, N)
 
 
@@ -372,13 +564,15 @@ def build_edge_sampler(knn_idx, weights, *, impl: str = "auto") -> EdgeSampler:
 
     ``impl="device"`` (the ``"auto"`` default) builds the alias table
     on device in one jitted computation — the (N, K) graph never touches
-    the host.  ``impl="host"`` is the numpy Vose oracle."""
+    the host — inside the host span ``lv.sampler.edge`` (``edges=``),
+    which closes once the tables are ready.  ``impl="host"`` is the numpy
+    Vose oracle."""
     if _resolve_impl(impl) == "device":
         knn_idx, weights = jnp.asarray(knn_idx), jnp.asarray(weights)
-        scope, hi_dtype = _pairing_scope()
-        with scope:
-            return _build_edge_sampler_device(knn_idx, weights,
-                                              hi_dtype=hi_dtype)
+        with spans.span("sampler.edge", edges=int(knn_idx.size)):
+            with jax.enable_x64(True):
+                es = _build_edge_sampler_device(knn_idx, weights)
+            return jax.block_until_ready(es)
     N, K = knn_idx.shape
     src = np.repeat(np.arange(N, dtype=np.int32), K)
     dst = np.asarray(knn_idx, np.int32).reshape(-1)
@@ -394,14 +588,15 @@ def build_edge_sampler(knn_idx, weights, *, impl: str = "auto") -> EdgeSampler:
 def build_negative_sampler(knn_idx, weights, *, power: float = 0.75,
                            impl: str = "auto") -> NodeSampler:
     """Weighted degree d_j = sum_i w_ij (directed, in+out), then ^power.
-    ``impl`` as in :func:`build_edge_sampler`."""
+    ``impl`` as in :func:`build_edge_sampler`; the device build runs
+    inside the host span ``lv.sampler.node`` (``nodes=``)."""
     if _resolve_impl(impl) == "device":
         knn_idx, weights = jnp.asarray(knn_idx), jnp.asarray(weights)
-        scope, hi_dtype = _pairing_scope()
-        with scope:
-            return _build_negative_sampler_device(knn_idx, weights,
-                                                  power=power,
-                                                  hi_dtype=hi_dtype)
+        with spans.span("sampler.node", nodes=int(knn_idx.shape[0])):
+            with jax.enable_x64(True):
+                ns = _build_negative_sampler_device(knn_idx, weights,
+                                                    power=power)
+            return jax.block_until_ready(ns)
     N, K = knn_idx.shape
     w = np.asarray(weights, np.float64)
     deg = w.sum(axis=1)                                   # out-degree
@@ -459,15 +654,15 @@ def edge_marginals(sampler) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=32)
-def _make_sharded_builder_fn(mesh, axis: str, n_real: int, power: float,
-                             hi_dtype):
+def _make_sharded_builder_fn(mesh, axis: str, n_real: int, power: float):
     """jit'd shard_map body building one shard's edge + negative tables.
 
-    Each shard pairs its OWN flat edge weights (local alias indices —
-    valid by construction, unlike a slab cut out of a global table) and
-    its own rows' degree^power masses; in-degree contributions landing
-    on other shards' rows travel through one O(N) ``psum``.  Per-shard
-    total masses come back stacked for the host-side (P,) shard table."""
+    Each shard pairs its OWN edge weights (local alias indices — valid by
+    construction, unlike a slab cut out of a global table) and its own
+    rows' degree^power masses; in-degree contributions landing on other
+    shards' rows travel through one O(N) ``psum``.  Per-shard total
+    masses come back stacked for the (P,) shard table.  Traced under
+    ``jax.enable_x64``, as :func:`_alias_tables` asks."""
     from jax.sharding import PartitionSpec as P
 
     n_shards = mesh.shape[axis]
@@ -475,29 +670,25 @@ def _make_sharded_builder_fn(mesh, axis: str, n_real: int, power: float,
     def body(idx_loc, w_loc, ids_loc):
         n_loc, K = idx_loc.shape
         w = jnp.maximum(w_loc.astype(jnp.float32), 0.0)
-        flat_w = ravel_rows(w)
 
         # --- edge table over this shard's own edges --------------------
         src = ids_loc.astype(jnp.int32)[row_major(n_loc, K)[0]]
-        dst = ravel_rows(idx_loc).astype(jnp.int32)
-        ethr, eali = _alias_pairing(flat_w, hi_dtype=hi_dtype)
-        t_edge = jnp.sum(flat_w.astype(hi_dtype))
+        dst = _flatten(idx_loc).astype(jnp.int32)
+        ethr, eali, t_edge = _alias_tables(_flatten(w))
 
         # --- negative table over this shard's own rows -----------------
         # deg_j = out_j + in_j; in-degree scatters land anywhere, so each
         # shard scatters into an O(N) partial and one psum completes it
         out_deg = jnp.sum(w, axis=1)
-        part = jnp.zeros((n_loc * n_shards,), jnp.float32)
-        part = part.at[dst].add(flat_w)
-        in_deg = jax.lax.psum(part, axis)
+        in_deg = jax.lax.psum(_in_degrees(idx_loc, w, n_loc * n_shards),
+                              axis)
         deg = out_deg + jax.lax.dynamic_slice_in_dim(in_deg, ids_loc[0],
                                                      n_loc)
         # exact zero for padded rows — a clamped epsilon^power would give
         # out-of-range node ids a small but nonzero draw probability
         mass = jnp.where(ids_loc < n_real,
                          jnp.maximum(deg, 1e-12) ** power, 0.0)
-        nthr, nali = _alias_pairing(mass, hi_dtype=hi_dtype)
-        t_node = jnp.sum(mass.astype(hi_dtype))
+        nthr, nali, t_node = _alias_tables(mass)
         return (src[None], dst[None], ethr[None], eali[None], t_edge[None],
                 nthr[None], nali[None], t_node[None])
 
@@ -528,13 +719,12 @@ def build_samplers_sharded(knn_idx, weights, *, power: float = 0.75,
     idx_p = sh.pad_rows(jnp.asarray(knn_idx, jnp.int32), n_shards)
     w_p = sh.pad_rows(jnp.asarray(weights, jnp.float32), n_shards)
     ids = jnp.arange(idx_p.shape[0], dtype=jnp.int32)
-    scope, hi_dtype = _pairing_scope()
-    with scope:
-        fn = _make_sharded_builder_fn(mesh, axis, N, float(power), hi_dtype)
+    with jax.enable_x64(True):
+        fn = _make_sharded_builder_fn(mesh, axis, N, float(power))
         (src, dst, ethr, eali, t_edge,
          nthr, nali, t_node) = fn(idx_p, w_p, ids)
-        sthr_e, sali_e = _alias_jit(t_edge, hi_dtype=hi_dtype)
-        sthr_n, sali_n = _alias_jit(t_node, hi_dtype=hi_dtype)
+        sthr_e, sali_e = _alias_jit(t_edge)
+        sthr_n, sali_n = _alias_jit(t_node)
     edge_s = ShardedEdgeSampler(src, dst, ethr, eali, sthr_e, sali_e,
                                 n_shards, N * K)
     node_s = ShardedNodeSampler(nthr, nali, sthr_n, sali_n, n_shards, N)

@@ -131,6 +131,31 @@ def largevis_grads(yi, yj, yneg, neg_mask, *, gamma=7.0, a=1.0, clip=5.0,
                                   eps=eps, neg_mask=neg_mask)
 
 
+def _edge_step_tiles(n: int, s: int, b: int, m: int, kw: dict) -> dict:
+    """Fill ``kw`` with the fused step's edge ``tile`` and row ``y_tile``:
+    autotuned, else the VMEM budget's (0 = one slab)."""
+    _tuned("largevis_edge_step", dict(n=n, b=b, m=m, s=s),
+           dict(tile=2048, y_tile=0), kw)
+    if not kw.get("y_tile"):
+        kw["y_tile"] = _fused_y_tile(n, s)
+    return kw
+
+
+def edge_step_slabs(n: int, s: int, b: int, m: int, *,
+                    layout_step: str = "auto") -> int:
+    """Row tiles (slabs of y) one edge step of an (n, s) embedding runs
+    over, as ``core.layout_engine.apply_edge_batch`` routes it: the
+    kernel's ``ceil(n / R)`` for slabs of R rows, and 1 for one slab or a
+    path without the kernel."""
+    kernel = layout_step == "fused" or (layout_step == "auto" and _on_tpu())
+    if not (kernel and fused_step_supported(n, s)):
+        return 1
+    y_tile = _edge_step_tiles(n, s, b, m, {})["y_tile"]
+    if not 0 < y_tile < n:
+        return 1
+    return -(-n // _round_up(y_tile, ROWS))
+
+
 def largevis_edge_step(y, i, j, negs, neg_mask, lr, *, gamma=7.0, a=1.0,
                        clip=5.0, eps=0.1, impl: str = "auto",
                        n_frozen: int = 0, **kw):
@@ -161,12 +186,8 @@ def largevis_edge_step(y, i, j, negs, neg_mask, lr, *, gamma=7.0, a=1.0,
     ``y_tile``, it is derived from the VMEM budget (0 = one slab).
     """
     if impl in ("fused", "pallas") or (impl == "auto" and _on_tpu()):
-        _tuned("largevis_edge_step",
-               dict(n=y.shape[0], b=i.shape[0], m=negs.shape[1],
-                    s=y.shape[1]),
-               dict(tile=2048, y_tile=0), kw)
-        if not kw.get("y_tile"):
-            kw["y_tile"] = _fused_y_tile(y.shape[0], y.shape[1])
+        _edge_step_tiles(y.shape[0], y.shape[1], i.shape[0], negs.shape[1],
+                         kw)
         return _lvstep_pallas(y, i, j, negs, neg_mask, lr, gamma=gamma,
                               a=a, clip=clip, eps=eps, n_frozen=n_frozen,
                               interpret=not _on_tpu(), **kw)

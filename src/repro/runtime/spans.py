@@ -28,11 +28,18 @@ Scopes and spans:
 ``lv.layout.sample``       edge and negative alias draws, collision mask, lr
 ``lv.layout.update``       ``apply_edge_batch``: the edge-step kernel with its
                            planar copies of y, or the split path
+``lv.alias.quantize``      the alias build's fixed-point masses
+``lv.alias.scan``          the alias build's chunk offsets of its prefix sums
+``lv.alias.pair``          the alias build's pairing of smalls and larges
 ``lv.explore.call``        host: one ``neighbor_explore`` call, ``rows=``
 ``lv.layout.dispatch``     host: one chunk of ``run_layout``'s scanned loop,
-                           ``steps=``
+                           ``steps=``, ``slabs=`` (row tiles of y per step)
 ``lv.layout.sync``         host: the chunk's ``block_until_ready`` when the
                            loop is monitored
+``lv.sampler.edge``        host: the device edge alias build, ``edges=``,
+                           until its tables are ready
+``lv.sampler.node``        host: the device noise alias build, ``nodes=``,
+                           until its tables are ready
 =========================  ====================================================
 """
 from __future__ import annotations

@@ -75,26 +75,24 @@ def _graph_stage_checks(n):
         limit_bytes=4 * nk, forbidden=[(n, K, K)],
         temp_limit_bytes=8 * nk)
 
-    # alias builds sort the (E,) weight vector in the f64 pairing scope:
-    # working set is a small multiple of E * 8 bytes, never E * K
-    scope, hi = S._pairing_scope()
-    with scope:
+    # alias builds walk the graph in chunks: the working set beyond the
+    # (E,) tables is a chunk's, never E * K
+    with jax.enable_x64(True):
         memcheck.check_stage(
             f"edge_sampler[n={n}]",
             S._build_edge_sampler_device.lower(
-                SDS((n, K), I32), SDS((n, K), F32), hi_dtype=hi),
+                SDS((n, K), I32), SDS((n, K), F32)),
             limit_bytes=6 * e * 8, forbidden=[(n, K, K)],
             temp_limit_bytes=16 * e * 8)
         memcheck.check_stage(
             f"neg_sampler[n={n}]",
             S._build_negative_sampler_device.lower(
-                SDS((n, K), I32), SDS((n, K), F32), power=0.75,
-                hi_dtype=hi),
+                SDS((n, K), I32), SDS((n, K), F32), power=0.75),
             limit_bytes=6 * e * 8, forbidden=[(n, K, K)],
             temp_limit_bytes=16 * e * 8)
         memcheck.check_stage(
             f"sampler_sharded[n={n}]",
-            S._make_sharded_builder_fn(mesh, "data", n, 0.75, hi).lower(
+            S._make_sharded_builder_fn(mesh, "data", n, 0.75).lower(
                 SDS((np_, K), I32), SDS((np_, K), F32), SDS((np_,), I32)),
             limit_bytes=6 * e * 8, forbidden=[(n, K, K)],
             temp_limit_bytes=16 * e * 8)

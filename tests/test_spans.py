@@ -157,6 +157,69 @@ def test_run_layout_records_one_dispatch_per_chunk(samplers):
     assert recs[-1][2]["steps"] == (res.steps % 16 or 16)
 
 
+def test_sampler_spans_close_once_tables_are_ready(monkeypatch):
+    """The device builders each add one record, ``edges=`` and
+    ``nodes=``, and wait for their tables inside the span; the host
+    route records nothing."""
+    rng = np.random.default_rng(5)
+    n, k = 300, 7
+    idx = rng.integers(0, n, (n, k)).astype(np.int32)
+    w = rng.uniform(0.5, 1.5, (n, k)).astype(np.float32)
+    waits = []
+    real = jax.block_until_ready
+
+    def spy(x):
+        waits.append((len(spans.records("sampler.edge")),
+                      len(spans.records("sampler.node"))))
+        return real(x)
+
+    e0 = len(spans.records("sampler.edge"))
+    n0 = len(spans.records("sampler.node"))
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    sampler_lib.build_edge_sampler(idx, w)
+    sampler_lib.build_negative_sampler(idx, w)
+    assert waits == [(e0, n0), (e0 + 1, n0)]
+    (t0, t1, counts, _), = spans.records("sampler.edge")[e0:]
+    assert counts == {"edges": n * k} and t1 > t0
+    (t0, t1, counts, _), = spans.records("sampler.node")[n0:]
+    assert counts == {"nodes": n} and t1 > t0
+    sampler_lib.build_edge_sampler(idx, w, impl="host")
+    sampler_lib.build_negative_sampler(idx, w, impl="host")
+    assert len(spans.records("sampler.edge")) == e0 + 1
+    assert len(spans.records("sampler.node")) == n0 + 1
+
+
+def test_dispatch_counts_the_slabs_of_y(monkeypatch):
+    """``slabs=`` on ``lv.layout.dispatch`` is the edge-step kernel's row
+    tiles, ``ceil(N / y_tile)``, and 1 untiled or off the kernel; a run
+    tiled into slabs gives the bits of one slab."""
+    from repro.configs.largevis_default import RoutingConfig
+    from repro.kernels import ops
+    rng = np.random.default_rng(6)
+    n, k = 5000, 4
+    idx = rng.integers(0, n, (n, k)).astype(np.int32)
+    w = rng.uniform(0.5, 1.5, (n, k)).astype(np.float32)
+    es = sampler_lib.build_edge_sampler(idx, w)
+    ns = sampler_lib.build_negative_sampler(idx, w)
+
+    def run(layout_step):
+        cfg = LargeVisConfig(samples_per_node=2, steps_per_dispatch=2,
+                             routing=RoutingConfig(layout_step=layout_step))
+        before = len(spans.records("layout.dispatch"))
+        y = layout_lib.run_layout(jax.random.key(2), es, ns, n, cfg).y
+        recs = spans.records("layout.dispatch")[before:]
+        return y, {c["slabs"] for _, _, c, _ in recs}
+
+    one, slabs = run("fused")
+    assert slabs == {1}
+    assert run("auto")[1] == {1}                 # the oracle on the CPU
+    monkeypatch.setattr(ops, "_FUSED_MAX_Y_BYTES", 2048 * 2 * 4)
+    assert ops._fused_y_tile(n, 2) == 2048
+    tiled, slabs = run("fused")
+    assert slabs == {3}
+    assert np.array_equal(np.asarray(tiled), np.asarray(one))
+
+
 def test_monitored_run_records_a_sync_per_dispatch(samplers):
     """The watchdog's blocked dispatch time is the dispatch span's start
     to the sync span's end."""

@@ -7,6 +7,8 @@ overruns).  They compile; nothing runs.  The topology is described inside
 a fixture: only the worker that runs this file loads the TPU compiler.
 """
 import functools
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -136,21 +138,44 @@ def test_largevis_grads(spec):
     assert "tpu_custom_call" in txt
 
 
-def test_alias_build(spec):
-    """The device alias-table build at E=2e7 (f32 on TPU)."""
-    fn = functools.partial(sampler._alias_jit, hi_dtype=jnp.float32)
-    txt = _compiled_text(fn, spec((20_000_000,)))
-    assert "tpu_custom_call" not in txt
+def _wikidoc_rows() -> int:
+    """N of the benchmark's ``wikidoc100`` configuration."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "configs", "wikidoc100.json")
+    with open(path) as f:
+        return json.load(f)["N"]
 
 
-def test_negative_sampler_build_70k(spec):
-    """The noise-table build on the 70k x 150 graph: a few seconds.  With
-    the graph flattened by ``reshape`` it took minutes to compile (the
-    relayout of a 150-wide minor dimension; see ``core.flat``)."""
-    fn = functools.partial(sampler._build_negative_sampler_device,
-                           power=0.75, hi_dtype=jnp.float32)
-    txt = _compiled_text(fn, spec((70_000, K), jnp.int32), spec((70_000, K)))
-    assert "tpu_custom_call" not in txt
+def _within_budget(fn, *args, **kw):
+    """Compile an alias build; its temporaries stay within two flat (E,)
+    arrays of 4 bytes beyond its outputs."""
+    with jax.enable_x64(True):
+        compiled = jax.jit(functools.partial(fn, **kw)).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    n, k = args[0].shape
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 2 * 4 * n * k, f"{temp / 1e9:.2f} GB of temporaries"
+
+
+@pytest.mark.parametrize("n", [836_756, "wikidoc100"])
+def test_alias_build(spec, n):
+    """The exact edge alias build at the layout cells' E: wikiword100's
+    1.26e8 and wikidoc100's (64-bit integer sums, taken by the TPU)."""
+    n = _wikidoc_rows() if n == "wikidoc100" else n
+    _within_budget(sampler._build_edge_sampler_device,
+                   spec((n, K), jnp.int32), spec((n, K)))
+
+
+@pytest.mark.parametrize("n", [70_000, 836_756, "wikidoc100"])
+def test_negative_sampler_build_70k(spec, n):
+    """The noise-table build on the 70k x 150 graph and the layout cells'
+    graphs: seconds.  With the graph flattened by ``reshape`` it took
+    minutes to compile (the relayout of a 150-wide minor dimension; see
+    ``core.flat``); scattered whole, the degrees held row-major copies of
+    the graph."""
+    n = _wikidoc_rows() if n == "wikidoc100" else n
+    _within_budget(sampler._build_negative_sampler_device,
+                   spec((n, K), jnp.int32), spec((n, K)), power=0.75)
 
 
 @pytest.fixture(scope="module")
