@@ -36,9 +36,14 @@ tile keeps each row's order: any row tiling gives the same bits.
 The planar copy of y is aliased input->output and stays in HBM; only one
 slab is ever in VMEM.  The drivers carry y as (N, s), so the wrapper
 pads and transposes y into that copy and back on every call: two
-embedding-sized HBM copies per step around the in-place kernel.  ``y_tile=R`` (rounded up to 1024 rows) picks the slab height;
-``ops.largevis_edge_step`` keeps the whole embedding as one slab up to the
-VMEM budget and tiles past it.
+embedding-sized HBM copies per step around the in-place kernel.
+``y_tile=R`` (rounded up to 1024 rows) picks the slab height;
+``ops.largevis_edge_step`` keeps the whole embedding as one slab up to
+3/4 of the core's VMEM (12,582,912 rows at s=2 on v5e) and tiles past it.
+Each row tile repeats both per-edge loops over all of the step's ids, so
+a slab count above 1 multiplies the kernel's time.  The wrapper asks the
+compiler for the scoped VMEM the slab and the edge scratch need
+(``vmem_limit_bytes``) only where that is above the compiler's default.
 
 ``n_frozen=`` is the out-of-sample transform mode: rows below ``n_frozen``
 are gathered and contribute forces but are never written, so a fitted
@@ -60,6 +65,36 @@ from repro.kernels.largevis_grad import LANES, _resolve_interpret, _round_up
 
 # y rows per (8, 128) f32 tile of one planar coordinate
 ROWS = 8 * LANES
+
+# The scoped VMEM a Mosaic kernel gets when it asks for none (v5e), and
+# one v5e TensorCore's VMEM, for a backend that cannot report its own:
+# the CPU, and ahead-of-time compiles for a described v5e.
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+_V5E_VMEM = 128 * 1024 * 1024
+# Room in a requested limit beyond the kernel's own buffers, for what the
+# compiler allocates itself.
+_VMEM_MARGIN = 1024 * 1024
+
+
+def vmem_capacity() -> int:
+    """VMEM bytes of one TensorCore: the chip's on a TPU backend, v5e's
+    elsewhere."""
+    if jax.default_backend() == "tpu":
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    return _V5E_VMEM
+
+
+def vmem_limit_bytes(s: int, rows: int, m: int, bp: int) -> int | None:
+    """Scoped VMEM the kernel asks for: None (the compiler's default)
+    while its buffers fit the default, else what they need, at most the
+    core's VMEM.  ``rows``: the slab's rows; ``bp``: the padded batch."""
+    need = 4 * (s * rows                  # the slab of y
+                + (2 + m) * s * bp        # gathered rows / staged updates
+                + 2 * (m + 1) * bp)       # mask and lr, double-buffered
+    need += _VMEM_MARGIN
+    if need <= DEFAULT_SCOPED_VMEM:
+        return None
+    return min(need, vmem_capacity())
 
 
 def _kernel(ids_ref, mask_ref, lr_ref, y_in, y_ref, slab, g_ref, *,
@@ -202,6 +237,7 @@ def fused_edge_step(y, i, j, negs, neg_mask, lr, *, gamma: float = 7.0,
     kern = functools.partial(_kernel, gamma=gamma, a=a, clip=clip, eps=eps,
                              m=M, s=s, b=B, tile=t, rq=R // LANES,
                              n_etiles=bp // t, n_frozen=n_frozen)
+    limit = vmem_limit_bytes(s, R, M, bp)
     out = pl.pallas_call(
         kern,
         grid=(2, n_rtiles, bp // t),
@@ -221,6 +257,8 @@ def fused_edge_step(y, i, j, negs, neg_mask, lr, *, gamma: float = 7.0,
         ],
         input_output_aliases={3: 0},
         interpret=interpret,
+        compiler_params=(None if limit is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=limit)),
     )(ids, mask.reshape(M, bp // LANES, LANES),
       lr.reshape(bp // LANES, LANES), yp)
     return out.reshape(s, n_pad).T[:N]

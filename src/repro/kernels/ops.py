@@ -16,15 +16,23 @@ from repro.kernels.knn_topk import pairwise_sqdist as _sqdist_pallas
 from repro.kernels.knn_topk import topk_sqdist as _topk_pallas
 from repro.kernels.largevis_grad import _round_up
 from repro.kernels.largevis_grad import largevis_grads as _lvgrad_pallas
-from repro.kernels.largevis_step import ROWS
+from repro.kernels.largevis_step import ROWS, vmem_capacity
 from repro.kernels.largevis_step import fused_edge_step as _lvstep_pallas
 from repro.runtime import autotune
 
-# VMEM budget for the fused edge-step kernel's resident slab of y.  The
-# slab is planar, (s, R/128, 128) f32, so it costs s*4 bytes per row with
-# R rounded up to 1024 rows: 8 MiB holds 1,048,576 rows at s=2.  Not a
-# support bound: past it the kernel tiles y into slabs (``y_tile``).
-_FUSED_MAX_Y_BYTES = 8 * 1024 * 1024
+# VMEM budget for the fused edge-step kernel's resident slab of y; None
+# derives it per call as 3/4 of the core's VMEM, leaving the last quarter
+# for the edge scratch (229 KB at B=4096), the mask and lr blocks and the
+# compiler's own.  The slab is planar, (s, R/128, 128) f32, so it costs
+# s*4 bytes per row with R rounded up to 1024 rows: v5e's 96 MiB holds
+# 12,582,912 rows at s=2.  Not a support bound: past it the kernel tiles
+# y into slabs (``y_tile``), each of which repeats the per-edge loops.
+_FUSED_MAX_Y_BYTES: int | None = None
+
+
+def _fused_y_budget() -> int:
+    """Bytes of y the fused step's resident slab may hold."""
+    return _FUSED_MAX_Y_BYTES or 3 * vmem_capacity() // 4
 
 
 def _tuned(kernel: str, shape: dict, default: dict, kw: dict) -> dict:
@@ -53,7 +61,7 @@ def fused_step_supported(n_nodes: int, out_dim: int) -> bool:
     """Whether ``largevis_edge_step`` may route to the fused step.
 
     True for ANY size on CPU and TPU: past the VMEM budget
-    (``_FUSED_MAX_Y_BYTES`` for the resident slab of y) the kernel tiles
+    (``_fused_y_budget`` for the resident slab of y) the kernel tiles
     y into slabs, bitwise-equal to one slab, so size is a tiling decision
     (``_fused_y_tile``), not a rejection.  Any other backend (GPU) gets
     the split path: there the kernel's sequential per-row update loop
@@ -70,9 +78,10 @@ def _fused_y_tile(n_nodes: int, out_dim: int) -> int:
     One slab while the whole planar embedding fits the VMEM budget —
     s*4 bytes per row, rows rounded up to 1024; past it, the largest
     multiple of 1024 rows whose slab stays inside the budget."""
-    if _round_up(n_nodes, ROWS) * out_dim * 4 <= _FUSED_MAX_Y_BYTES:
+    budget = _fused_y_budget()
+    if _round_up(n_nodes, ROWS) * out_dim * 4 <= budget:
         return 0
-    return max(ROWS, _FUSED_MAX_Y_BYTES // (4 * out_dim) // ROWS * ROWS)
+    return max(ROWS, budget // (4 * out_dim) // ROWS * ROWS)
 
 
 def pairwise_sqdist(a, b, *, impl: str = "auto", **kw):

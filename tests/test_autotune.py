@@ -340,10 +340,23 @@ def test_tiled_hlo_no_second_full_embedding():
 
 
 def test_fused_step_supported_lifts_size_bound():
-    """The 8 MiB VMEM ceiling is a tiling decision now, not a routing
-    rejection: any N is supported, with a tile chosen past the budget."""
-    assert ops.fused_step_supported(10_000_000, 2)
+    """The VMEM ceiling is a tiling decision, not a routing rejection:
+    any N is supported, with a tile chosen past the budget (3/4 of the
+    core's VMEM: 12,582,912 rows at s=2 on v5e)."""
+    n = 16_000_000
+    assert ops.fused_step_supported(n, 2)
     assert ops._fused_y_tile(100, 2) == 0          # fits: stay untiled
-    big_tile = ops._fused_y_tile(10_000_000, 2)
-    assert 0 < big_tile < 10_000_000
-    assert 4 * 2 * big_tile <= ops._FUSED_MAX_Y_BYTES
+    big_tile = ops._fused_y_tile(n, 2)
+    assert 0 < big_tile < n
+    assert 4 * 2 * big_tile <= ops._fused_y_budget()
+
+
+@pytest.mark.parametrize("n,slabs", [(70_000, 1), (2_228_224, 1),
+                                     (2_837_395, 1), (12_582_912, 1),
+                                     (12_582_913, 2), (40_000_000, 4)])
+def test_edge_step_slabs_follow_the_budget(tuner, n, slabs):
+    """The slab count the dispatch span records: one slab up to the VMEM
+    budget (the wikidoc100 configuration's 2,228,224 rows and WikiDoc's
+    published 2,837,395 among them), then ceil(n / tile)."""
+    autotune.set_mode("cache")
+    assert ops.edge_step_slabs(n, 2, 4096, 5, layout_step="fused") == slabs

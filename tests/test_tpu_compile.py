@@ -9,6 +9,7 @@ a fixture: only the worker that runs this file loads the TPU compiler.
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 from repro.core import sampler, transform
 from repro.kernels import knn_topk, ops
 from repro.kernels.largevis_grad import largevis_grads
-from repro.kernels.largevis_step import fused_edge_step
+from repro.kernels.largevis_step import (DEFAULT_SCOPED_VMEM, ROWS,
+                                        fused_edge_step, vmem_capacity,
+                                        vmem_limit_bytes)
 
 K, M, B = 150, 5, 4096
 
@@ -103,22 +106,71 @@ def _edge_batch(spec, n, b=B):
             spec((b, M), jnp.int32), spec((b, M)), spec(()))
 
 
-@pytest.mark.parametrize("n", [70_000, 1 << 20])
+def _budget_edge() -> int:
+    """The largest N the VMEM budget leaves untiled at s=2."""
+    return ops._fused_y_budget() // (4 * 2)
+
+
+@pytest.mark.parametrize("n", [70_000, 1 << 20, 2_228_224, 2_837_395,
+                               "budget_edge"])
 def test_fused_edge_step_one_slab(spec, n):
     """Untiled: the whole planar embedding is one VMEM slab, up to the
-    largest N the VMEM budget leaves untiled (2^20 rows at s=2)."""
+    largest N the VMEM budget leaves untiled (3/4 of v5e's 128 MiB:
+    12,582,912 rows at s=2).  2,228,224 rows is the wikidoc100
+    configuration's N, 2,837,395 WikiDoc's published one."""
+    n = _budget_edge() if n == "budget_edge" else n
     assert ops._fused_y_tile(n, 2) == 0
     fn = functools.partial(fused_edge_step, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, *_edge_batch(spec, n))
 
 
 def test_fused_edge_step_tiled(spec):
-    """N=4M at s=2 is past the budget: the kernel tiles y into slabs."""
-    n = 4_000_000
+    """N=16M at s=2 is past the budget: the kernel tiles y into slabs."""
+    n = 16_000_000
+    assert ops._fused_y_tile(_budget_edge() + 1, 2) > 0
     y_tile = ops._fused_y_tile(n, 2)
     assert 0 < y_tile < n
     fn = functools.partial(fused_edge_step, interpret=False, y_tile=y_tile)
     assert "tpu_custom_call" in _compiled_text(fn, *_edge_batch(spec, n))
+
+
+def _scoped_vmem_asked(spec, n, s):
+    """The scoped-VMEM size the edge step's lowering asks the compiler
+    for, or None where it asks for none (the compiler's default)."""
+    args = list(_edge_batch(spec, n))
+    args[0] = spec((n, s))
+    txt = jax.jit(functools.partial(fused_edge_step, interpret=False)).lower(
+        *args).as_text()
+    config = re.search(r'tpu_custom_call.*?backend_config = "(.*?)"', txt)
+    config = json.loads(config.group(1).replace("\\22", '"'))
+    scoped = config.get("scoped_memory_configs")
+    return scoped[0]["size"] if scoped else None
+
+
+@pytest.mark.parametrize("n,s", [(70_000, 2), (836_756, 2), (1 << 20, 2),
+                                 (2_228_224, 2), (2_228_224, 3),
+                                 ("budget_edge", 2)])
+def test_fused_edge_step_vmem_limit(spec, n, s):
+    """The kernel names no scoped-VMEM limit while its buffers fit the
+    compiler's 16 MiB default, so the programs of those shapes are what
+    they were; past it, it asks for the slab and the edge scratch, and
+    never for more than the core's VMEM."""
+    n = _budget_edge() if n == "budget_edge" else n
+    rows = -(-n // ROWS) * ROWS
+    buffers = 4 * (s * rows + (2 + M) * s * B)    # slab, edge scratch
+    asked = _scoped_vmem_asked(spec, n, s)
+    assert asked == vmem_limit_bytes(s, rows, M, B)
+    if buffers + (1 << 20) <= DEFAULT_SCOPED_VMEM:
+        assert asked is None
+    else:
+        assert buffers < asked <= vmem_capacity()
+
+
+def test_vmem_limit_never_past_the_core():
+    """A slab too large for the core asks for the core's VMEM, which the
+    compiler then refuses, and never for more."""
+    assert vmem_limit_bytes(2, 40_000_000, M, B) == vmem_capacity()
+    assert vmem_limit_bytes(2, 1 << 20, M, B) is None
 
 
 def test_fused_edge_step_frozen_serving_batch(spec):
